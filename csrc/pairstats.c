@@ -426,6 +426,16 @@ void galah_window_match_counts_merge(
     merge_count_resolve()(qh, qw, nq, ref, H, matched);
 }
 
+/* Per-window matched counts from per-element 0/1 membership flags:
+ * matched[qw[i]] += flags[i]. The host fold after the device
+ * window-match kernel (ops/pallas_fragment.py) — one pass, outside the
+ * Python lock, so several pairs fold at once while the device runs. */
+void galah_fold_window_flags(const int8_t *flags, const int32_t *qw,
+                             int64_t n, int32_t *matched) {
+    for (int64_t i = 0; i < n; i++)
+        matched[qw[i]] += flags[i];
+}
+
 /* Capability probe for the test harness: 1 iff the merge counter
  * would dispatch to the AVX-512 kernel right now (build support +
  * CPU support + GALAH_TPU_NO_AVX512 unset). Lets the A/B identity

@@ -74,7 +74,6 @@ THREADED_MODULES = (
     "galah_tpu/resilience/policy.py",
     "galah_tpu/resilience/faults.py",
     "galah_tpu/utils/timing.py",
-    "galah_tpu/ops/sketch_stream.py",
     "galah_tpu/index/store.py",
     "galah_tpu/index/incremental.py",
     "galah_tpu/fleet/scheduler.py",

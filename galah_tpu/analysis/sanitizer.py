@@ -38,7 +38,7 @@ merged into run_report.json (schema v4) by obs.report.
 Known limitations, by design (all covered lexically by GL8xx):
 rebinding a module *global* from inside its own module bypasses module
 ``__setattr__`` (STORE_GLOBAL writes the dict directly), so scalar
-latches like ``sketch_stream._DEMOTED`` are checked lexically only;
+latches are checked lexically only;
 mutations of *nested* containers (a dict inside a guarded dict) are
 one level too deep for the instrumented containers; and a module-level
 guarded container that is re-*bound* (rather than mutated) sheds its

@@ -127,18 +127,20 @@ def _lattice() -> List[Tuple[str, str, Callable[[], object],
             sds((b, k), u64), sds((b, k), u64),
             sketch_size=k, block_pairs=8)
 
-    # blocked fragment window-match kernel: job-bucket x span-bucket
-    # lattice points at the production geometry (8 sublanes x 128
-    # lanes per job, u32 hi/lo planes — the u64 split happens on the
-    # host, so the device boundary is 32-bit by construction)
+    # fragment window-match kernel: job-bucket x span-bucket lattice
+    # points at the production geometry (8 sublanes x 128 lanes per
+    # job, int32 hi/lo planes — the u64 split happens on the host, so
+    # the device boundary is 32-bit by construction; int32 per-job row
+    # plans; int8 flags out)
     fragment = get("galah_tpu.ops.pallas_fragment", "_window_hits_jit")
-    u32 = jnp.uint32
+    i32 = jnp.int32
     for jobs, span in ((8, 1), (8, 2), (16, 4)):
         add("pallas_fragment._window_hits_jit",
-            f"jobs={jobs},span={span},uint32", fragment,
-            sds((jobs * 8, 128), u32), sds((jobs * 8, 128), u32),
-            sds((jobs * span * 8, 128), u32),
-            sds((jobs * span * 8, 128), u32),
+            f"jobs={jobs},span={span},int32", fragment,
+            sds((jobs,), i32), sds((jobs,), i32),
+            sds((jobs * 8, 128), i32), sds((jobs * 8, 128), i32),
+            sds((jobs * span * 8, 128), i32),
+            sds((jobs * span * 8, 128), i32),
             span=span, interpret=True)
 
     # quarantined murmur3 kernel keeps its boundary contract pinned too

@@ -253,8 +253,8 @@ def quality_order_genomes(
         from galah_tpu.obs.events import warn_once
 
         # Repeated construction (bench rungs, embedding tools) must not
-        # repeat this once-per-run fact — BENCH_r05's tail carried one
-        # copy per in-process bench stage. The explicit key dedupes
+        # repeat this once-per-run fact — once per in-process bench
+        # stage otherwise. The explicit key dedupes
         # across every module that might phrase the same fact.
         warn_once(logger, missing_msg, key=missing_key)
         return list(genome_paths), False

@@ -164,8 +164,9 @@ class ProfileStore:
                 by_path[p] = prof
             else:
                 misses.append(p)
-        from galah_tpu.ops.hashing import device_transfer_bound
-
+        # per-genome C builds on `threads` workers, else grouped device
+        # hashing batches
+        batched = not fragment_ani._c_profile_available(self.k)
         for p, prof in process_stream(
                 iter_prefetched(misses, read_genome,
                                 depth=max(2, self.threads)),
@@ -179,8 +180,7 @@ class ProfileStore:
                     g, k=self.k, fraglen=self.fraglen,
                     subsample_c=self.subsample_c,
                     hash_algorithm=self.hash_algorithm),
-                batched=device_transfer_bound(),
-                workers=self.threads):
+                batched=batched, workers=self.threads):
             self._store_disk(p, prof)
             self._insert(p, prof)
             by_path[p] = prof

@@ -86,7 +86,8 @@ class HLLPreclusterer(PreclusterBackend):
                 lambda _path, g: hll.hll_sketch_genome(
                     g, p=self.p, k=self.k, seed=self.seed,
                     algo=self.algo),
-                batched=device_transfer_bound(),
+                batched=(device_transfer_bound()
+                         and not hll.c_hll_available(self.k, self.p)),
                 workers=self.threads):
             by_path[path] = row
             from galah_tpu.obs import metrics as obs_metrics

@@ -125,6 +125,12 @@ def cluster(
     (greedy-device-demoted). Decisions are bit-identical either way
     (docs/cluster_engine.md).
     """
+    from galah_tpu.ops import pallas_fragment
+    from galah_tpu.resilience import dispatch as rdispatch
+
+    rdispatch.begin_run()
+    # device planes of an earlier run's profiles are dead weight in HBM
+    pallas_fragment.clear_plane_cache()
     skip_clusterer = preclusterer.method_name() == clusterer.method_name()
     if skip_clusterer:
         logger.info(

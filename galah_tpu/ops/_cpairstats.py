@@ -273,6 +273,32 @@ _fn_wmm.argtypes = [
 ]
 
 
+_fn_fold = _lib.galah_fold_window_flags
+_fn_fold.restype = None
+_fn_fold.argtypes = [
+    ctypes.POINTER(ctypes.c_int8), ctypes.POINTER(ctypes.c_int32),
+    ctypes.c_int64, ctypes.POINTER(ctypes.c_int32),
+]
+
+
+def fold_window_flags(flags: np.ndarray, qw: np.ndarray,
+                      n_windows: int) -> np.ndarray:
+    """Per-window matched counts from 0/1 membership flags aligned to
+    a profile's sorted query (qw its window ids, all < n_windows, as
+    GenomeProfile.sorted_query guarantees) — np.bincount(qw[flags !=
+    0], minlength=n_windows) as int32, without holding the GIL."""
+    flags = np.ascontiguousarray(flags, dtype=np.int8)
+    qw = np.ascontiguousarray(qw, dtype=np.int32)
+    if flags.shape != qw.shape:
+        raise ValueError("flags/qw shape mismatch")
+    matched = np.zeros(n_windows, dtype=np.int32)
+    _fn_fold(flags.ctypes.data_as(ctypes.POINTER(ctypes.c_int8)),
+             qw.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+             flags.shape[0],
+             matched.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
+    return matched
+
+
 _fn_avx = _lib.galah_merge_uses_avx512
 _fn_avx.restype = ctypes.c_int
 _fn_avx.argtypes = []

@@ -225,8 +225,7 @@ def positional_hashes(genome: Genome, k: int,
     n = genome.codes.shape[0]
     if n < k:
         return np.zeros(0, dtype=np.uint64)
-    if (jax.default_backend() == "cpu" and k <= 32
-            and chunk == hashing.DEFAULT_CHUNK):
+    if k <= 32 and chunk == hashing.DEFAULT_CHUNK:
         try:
             from galah_tpu.ops import _csketch
 
@@ -319,9 +318,13 @@ def _profile_from_flat(path: str, flat: np.ndarray, k: int, fraglen: int,
 
 def _c_profile_available(k: int) -> bool:
     """Gate for the C single-pass profile build — genome-independent
-    by construction (backend, k width, toolchain), so callers may
-    decide once per batch."""
-    if jax.default_backend() != "cpu" or k > 32:
+    by construction (k width, toolchain), so callers may decide once
+    per batch. It holds on every backend: a profile is host data (every
+    positional hash comes back to the host), so the device pass saves
+    no transfer, and on a v5e chip its batched hashing of real-size
+    genomes compiled one variant per length bucket and then exceeded
+    HBM."""
+    if k > 32:
         return False
     try:
         from galah_tpu.ops import _csketch  # noqa: F401
@@ -388,9 +391,8 @@ def build_profiles_batch(genomes, k: int, fraglen: int,
     over all files, src/skani.rs:46)."""
     _check_subsample(subsample_c)  # fail before any device hashing
     if genomes and _c_profile_available(k):
-        # CPU backend with the C walker: per-genome single-pass builds
-        # beat device batch grouping (no dispatch round trips to
-        # amortize).
+        # With the C walker, per-genome single-pass builds beat device
+        # batch grouping.
         return [_profile_via_c(g, k, fraglen, subsample_c,
                                algo=hash_algorithm)
                 for g in genomes]
@@ -696,7 +698,7 @@ def _directed_ani_arrays_c(
     frags_matching, frags_total) ARRAYS — the per-pair Python loop
     costs ~100x the O(nq + H) merge itself at small-genome sizes,
     which is the entire wall of the dense-similarity mega-family
-    regime (BASELINE.md rung-mega row; reference analog: one skani
+    regime (reference analog: one skani
     call per screened pair, src/skani.rs:85-104)."""
     from galah_tpu.ops._cpairstats import window_match_counts_merge_batch
 
@@ -721,6 +723,7 @@ def _directed_ani_arrays_c(
         pair_q[n] = qi
         pair_r[n] = ri
 
+    _warm_sorted_queries(queries, threads)
     qh_parts, qw_parts, tot_parts = [], [], []
     for q in q_profiles:
         qh, qw, totals = q.sorted_query()
@@ -809,6 +812,22 @@ def _c_merge_available() -> bool:
     return hasattr(_cpairstats, "window_match_counts_merge")
 
 
+def _warm_sorted_queries(queries: "list[Tuple[GenomeProfile, GenomeProfile]]",
+                         threads: int) -> None:
+    """Build each distinct query profile's sorted_query cache, on up to
+    `threads` pool workers: its argsort (~0.2-0.3 s for a 3 Mbp genome)
+    releases the GIL, and a catalogue's exact-ANI pass needs one per
+    genome before its first pair."""
+    todo = [q for q in {id(q): q for q, _ in queries}.values()
+            if q._np_sorted_query is None]
+    if threads > 1 and len(todo) > 1:
+        from galah_tpu.io.prefetch import _shared_pool
+
+        list(_shared_pool(threads).map(GenomeProfile.sorted_query, todo))
+    for q in todo:
+        q.sorted_query()
+
+
 def _resolve_fragment_strategy(
     backend: "Optional[str]" = None,
     n_devices: "Optional[int]" = None,
@@ -864,27 +883,16 @@ def directed_ani_batch(
     """
     if not queries:
         return []
-    strategy, explicit = _resolve_fragment_strategy()
+    strategy, _explicit = _resolve_fragment_strategy()
     timing.counter(f"fragment-strategy-{strategy}", 1)
     if strategy == "c":
         return _directed_ani_batch_cmerge(
             queries, identity_floor, min_window_valid_frac, threads)
-
-    from galah_tpu.ops._fallback import run_with_pallas_fallback
-
-    def run(pallas: bool) -> "list[DirectedANI]":
-        if pallas:
-            return _directed_ani_batch_pallas(
-                queries, identity_floor, min_window_valid_frac)
-        return _directed_ani_batch_xla(
-            queries, identity_floor, min_window_valid_frac)
-
-    res, used = run_with_pallas_fallback(
-        "fragment window-match kernel", explicit,
-        strategy == "pallas", run)
-    if strategy == "pallas" and not used:
-        timing.counter("fragment-pallas-demoted", 1)
-    return res
+    if strategy == "pallas":
+        return _directed_ani_batch_pallas(
+            queries, identity_floor, min_window_valid_frac, threads)
+    return _directed_ani_batch_xla(
+        queries, identity_floor, min_window_valid_frac)
 
 
 def _directed_ani_batch_cmerge(
@@ -932,8 +940,7 @@ def _directed_ani_batch_cmerge(
         # (one candidate vs many refs is the common shape).
         from galah_tpu.io.prefetch import _shared_pool
 
-        for q in {id(q): q for q, _ in queries}.values():
-            q.sorted_query()
+        _warm_sorted_queries(queries, threads)
         # The shared pool is sized to the LARGEST worker count
         # ever requested in-process; keep at most `threads`
         # futures outstanding so a smaller knob here still
@@ -1028,35 +1035,47 @@ def _directed_ani_batch_pallas(
     queries: "list[Tuple[GenomeProfile, GenomeProfile]]",
     identity_floor: float,
     min_window_valid_frac: float,
+    threads: int = 1,
 ) -> "list[DirectedANI]":
-    """The blocked Mosaic strategy (ops/pallas_fragment.py): queries
-    grouped by padded shape bucket like the XLA path — the kernel's
-    launch packer then covers each bucket's pairs with as few grid
-    launches as its volume caps allow. Per-ELEMENT membership flags
-    come back host-side; one bincount per pair folds them into the
-    same per-window matched counts the other strategies produce, and
-    the shared _directed_from_counts_arrays reduction keeps the
-    DirectedANI floats bit-identical."""
+    """The Mosaic strategy (ops/pallas_fragment.py): one launch per
+    directed pair on device-resident hash planes, a few in flight.
+    Per-ELEMENT membership flags come back host-side; one bincount per
+    pair folds them into the same per-window matched counts the other
+    strategies produce while the next launches run, and the shared
+    _directed_from_counts_arrays reduction keeps the DirectedANI
+    floats bit-identical."""
     from galah_tpu.ops import pallas_fragment
 
     # interpret-mode on non-TPU backends: parity tests pin the
     # strategy on CPU; a real TPU lowers through Mosaic
     interpret = jax.default_backend() != "tpu"
+    _warm_sorted_queries(queries, threads)
     out: "list[Optional[DirectedANI]]" = [None] * len(queries)
     groups: "dict[tuple, list[int]]" = {}
-    for n, (q, r) in enumerate(queries):
-        key = (q.padded_windows().shape, r.padded_ref_set().shape[0],
-               q.k, q.fraglen, q.subsample_c)
-        groups.setdefault(key, []).append(n)
+    for n, (q, _r) in enumerate(queries):
+        groups.setdefault((q.k, q.fraglen, q.subsample_c), []).append(n)
 
-    for (_w, _h, k, fraglen, subsample_c), idxs in groups.items():
+    for (k, fraglen, subsample_c), idxs in groups.items():
+        # pairs sharing a reference run back to back, so its planes
+        # stay resident while its queries stream past; the query order
+        # reverses from one reference to the next, so the queries the
+        # cache still holds come first
+        by_ref: "dict[int, list[int]]" = {}
+        for n in idxs:
+            by_ref.setdefault(id(queries[n][1]), []).append(n)
+        idxs = []
+        for g, group in enumerate(by_ref.values()):
+            group.sort(key=lambda n: id(queries[n][0]), reverse=g % 2 == 1)
+            idxs += group
         items = []
         for n in idxs:
             q, r = queries[n]
-            items.append(
-                (q.sorted_query()[0], r.ref_set, r.padded_ref_set()))
-        hits = pallas_fragment.window_element_hits(
-            items, interpret=interpret)
+            items.append((q.sorted_query()[0], r.ref_set))
+        matched_by_item = {}
+        for j, flags in pallas_fragment.iter_element_hits(
+                items, interpret=interpret):
+            _qh, qw, totals = queries[idxs[j]][0].sorted_query()
+            matched_by_item[j] = _fold_flags(flags, qw, totals.shape[0])
 
         matched_parts, total_parts, starts, live = [], [], [], []
         seg = 0
@@ -1069,9 +1088,7 @@ def _directed_ani_batch_pallas(
                 # zero-window result is all-zero by definition
                 out[n] = DirectedANI(0.0, 0.0, 0, 0)
                 continue
-            matched = np.bincount(
-                qw[hits[j] != 0], minlength=w).astype(np.int32)
-            matched_parts.append(matched)
+            matched_parts.append(matched_by_item[j])
             total_parts.append(totals)
             starts.append(seg)
             seg += w
@@ -1087,6 +1104,19 @@ def _directed_ani_batch_pallas(
             out[n] = DirectedANI(float(ani[i]), float(af[i]),
                                  int(fm[i]), int(ft[i]))
     return out  # type: ignore[return-value]
+
+
+def _fold_flags(flags: np.ndarray, qw: np.ndarray,
+                n_windows: int) -> np.ndarray:
+    """Per-window matched counts from a pair's per-element membership
+    flags: the compiled-C fold (~10x numpy's bincount at MAG length)
+    where the toolchain built it."""
+    try:
+        from galah_tpu.ops._cpairstats import fold_window_flags
+    except ImportError:
+        return np.bincount(qw[flags != 0],
+                           minlength=n_windows).astype(np.int32)
+    return fold_window_flags(flags, qw, n_windows)
 
 
 def _shard_batch(pairs: "list[Tuple[GenomeProfile, GenomeProfile]]",

@@ -62,6 +62,20 @@ def _hll_update(regs: jax.Array, hashes: jax.Array, p: int) -> jax.Array:
     return regs.at[idx].max(rho)
 
 
+def c_hll_available(k: int, p: int) -> bool:
+    """Gate for the compiled-C register walk. It holds on every backend:
+    registers are host data (2^p bytes per genome, one pass over it), and
+    on a v5e chip the batched device pass of MAG-length genomes laid its
+    (8, L) hash rows out 16x padded and asked for twice the chip's HBM."""
+    if k > 32 or not 1 <= p <= 24:
+        return False
+    try:
+        from galah_tpu.ops import _csketch  # noqa: F401
+    except ImportError:
+        return False
+    return True
+
+
 def hll_sketch_genome(
     genome: Genome,
     p: int = DEFAULT_P,
@@ -72,23 +86,15 @@ def hll_sketch_genome(
 ) -> np.ndarray:
     """(2^p,) uint8 HLL registers over the genome's canonical k-mers.
 
-    On a single-device CPU backend the compiled-C walker runs instead
+    Where c_hll_available() holds, the compiled-C walker runs
     (csrc/sketch.c::galah_hll_registers, bit-identical); an explicit
-    non-default chunk pins the JAX path. The device_count() == 1
-    condition matches every other native-path gate (the op is
-    per-genome so results would be identical either way; one rule for
-    all gates keeps the policy auditable)."""
-    if (jax.default_backend() == "cpu" and jax.device_count() == 1
-            and k <= 32 and 1 <= p <= 24
-            and chunk == hashing.DEFAULT_CHUNK):
-        try:
-            from galah_tpu.ops import _csketch
+    non-default chunk pins the JAX path."""
+    if c_hll_available(k, p) and chunk == hashing.DEFAULT_CHUNK:
+        from galah_tpu.ops import _csketch
 
-            return _csketch.hll_registers(
-                genome.codes, genome.contig_offsets, k=k, p=p,
-                seed=seed, algo=algo)
-        except ImportError:
-            pass  # no C toolchain: fall through to the JAX path
+        return _csketch.hll_registers(
+            genome.codes, genome.contig_offsets, k=k, p=p,
+            seed=seed, algo=algo)
     regs = jnp.zeros((1 << p,), dtype=jnp.uint8)
     for hashes, _pos, _n_new in hashing.iter_chunk_hashes(
             genome.codes, genome.contig_offsets, k=k, chunk=chunk,
@@ -303,25 +309,15 @@ def hll_threshold_pairs(
             row_tile=row_tile, col_tile=col_tile,
             cap_per_row=cap_per_row)
 
-    # Fall back to XLA on Mosaic failure ONLY when pallas was chosen by
-    # default: an explicit use_pallas=True pins the kernel so parity
-    # tests fail loudly instead of vacuously comparing XLA to XLA.
-    explicit = use_pallas is not None
+    # The Mosaic kernel by default on TPU; its failures propagate. It
+    # is compiled and validated at the 128x128 output tile geometry
+    # (square tiles keep the out block at the native (8,128)-register
+    # multiple).
     if use_pallas is None:
         use_pallas = use_pallas_default()
-    from galah_tpu.ops._fallback import run_with_pallas_fallback
-
-    # The Mosaic kernel is compiled/validated at the 128x128 output
-    # tile geometry (square tiles keep the out block at the native
-    # (8,128)-register multiple); other shapes have hit remote-compile
-    # hangs on v5e.
-    result, _ = run_with_pallas_fallback(
-        "HLL kernel", explicit, bool(use_pallas),
-        lambda p: _hll_threshold_single(
-            regs_mat, k, min_ani, 128 if p else row_tile,
-            128 if p else col_tile, p, cap_per_row),
-        fallback_label="the XLA union-stats path")
-    return result
+    return _hll_threshold_single(
+        regs_mat, k, min_ani, 128 if use_pallas else row_tile,
+        128 if use_pallas else col_tile, bool(use_pallas), cap_per_row)
 
 
 def _hll_threshold_single(

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from galah_tpu.utils.jax_compat import shard_map
 
 from galah_tpu.ops.constants import SENTINEL
 from galah_tpu.ops.hashing import HASH_SENTINEL
@@ -176,7 +175,7 @@ def all_pairs_ani(
     mat[:n] = sketch_mat
     jmat = jnp.asarray(mat)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_block_ani, sketch_size=sketch_size, k=k,
                           col_tile=col_tile),
         mesh=mesh,
@@ -370,7 +369,8 @@ def screen_pairs(
 
     if (mesh is None and use_pallas is None and row_tile is None
             and col_tile is None
-            and marker_mat.shape[0] >= sparse_screen_min_n()
+            and (marker_mat.shape[0] >= sparse_screen_min_n()
+                 or not _dense_marker_screen_default(marker_mat.shape[1]))
             and not os.environ.get("GALAH_TPU_DENSE_PAIRS")):
         from galah_tpu.ops.collision import collision_pair_counts
 
@@ -406,24 +406,37 @@ def screen_pairs(
             col_tile=col_tile if col_tile is not None else 256,
             cap_per_row=cap_per_row, use_pallas=use_pallas)
 
-    # Mosaic intersect kernel on TPU by default, with the same
-    # explicit-pin / default-fallback policy as threshold_pairs.
-    explicit = use_pallas is not None
+    # Mosaic intersect kernel on TPU by default, for rows it compiles
+    # for; a kernel failure propagates, whichever way it was chosen.
     if use_pallas is None:
-        from galah_tpu.ops.hll import use_pallas_default
-
-        use_pallas = use_pallas_default()
+        use_pallas = _marker_pallas_default(marker_mat.shape[1])
     # per-path tile defaults, honoring explicit caller values
-    from galah_tpu.ops._fallback import run_with_pallas_fallback
+    return _screen_pairs_single(
+        marker_mat, counts, c_floor,
+        row_tile if row_tile is not None else (128 if use_pallas else 64),
+        col_tile if col_tile is not None else 256,
+        cap_per_row, bool(use_pallas))
 
-    result, _ = run_with_pallas_fallback(
-        "intersect kernel", explicit, bool(use_pallas),
-        lambda p: _screen_pairs_single(
-            marker_mat, counts, c_floor,
-            row_tile if row_tile is not None else (128 if p else 64),
-            col_tile if col_tile is not None else 256,
-            cap_per_row, p))
-    return result
+
+def _dense_marker_screen_default(width: int) -> bool:
+    """Below the sparse crossover, whether the dense tiled screen is
+    the default: everywhere but a TPU whose rows are too wide for the
+    Mosaic kernel. There the XLA twin compares every marker pair of
+    every tile — a 256-genome catalogue of MAGs (8,064-wide rows) took
+    158 s of a v5e chip — while the host collision screen is exact and
+    takes seconds."""
+    from galah_tpu.ops.hll import use_pallas_default
+
+    return not use_pallas_default() or _marker_pallas_default(width)
+
+
+def _marker_pallas_default(width: int) -> bool:
+    """The marker screen's default kernel choice: Mosaic on a TPU
+    backend up to pallas_pairwise.MAX_DEFAULT_WIDTH markers per row."""
+    from galah_tpu.ops.hll import use_pallas_default
+    from galah_tpu.ops.pallas_pairwise import MAX_DEFAULT_WIDTH
+
+    return use_pallas_default() and width <= MAX_DEFAULT_WIDTH
 
 
 def _screen_pairs_single(
@@ -489,7 +502,7 @@ def threshold_pairs(
     """Sparse {(i, j): ani} for i<j pairs with ani >= min_ani.
 
     row_tile/col_tile default per path (XLA: 64x128; Mosaic: 128x512);
-    explicit values are honored on every path, including the fallback.
+    explicit values are honored on every path.
 
     One device dispatch per ROW BLOCK (not per tile): the block's stats
     stripe is computed tile-by-tile on device (`lax.map`), thresholded
@@ -569,10 +582,7 @@ def threshold_pairs(
             row_tile=row_tile, col_tile=col_tile,
             cap_per_row=cap_per_row, use_pallas=use_pallas)
 
-    # An explicit use_pallas=True pins the Mosaic kernel (failures
-    # propagate, keeping parity tests honest); only the default choice
-    # falls back to XLA on Mosaic failure.
-    explicit = use_pallas is not None
+    # The Mosaic kernel by default on TPU; its failures propagate.
     if use_pallas is None:
         from galah_tpu.ops.hll import use_pallas_default
 
@@ -586,16 +596,8 @@ def threshold_pairs(
 
     if sketch_size is None:
         sketch_size = sketch_mat.shape[1]
-    from galah_tpu.ops._fallback import run_with_pallas_fallback
-
-    result, _ = run_with_pallas_fallback(
-        "pair-stats kernel", explicit, bool(use_pallas),
-        lambda p: _threshold_pairs_single(
-            sketch_mat, k, min_ani, sketch_size,
-            rt if p else (row_tile if row_tile is not None else 64),
-            ct if p else (col_tile if col_tile is not None else 128),
-            p, cap_per_row))
-    return result
+    return _threshold_pairs_single(sketch_mat, k, min_ani, sketch_size,
+                                   rt, ct, bool(use_pallas), cap_per_row)
 
 
 def _threshold_pairs_single(

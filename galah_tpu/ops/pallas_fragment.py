@@ -1,63 +1,49 @@
-"""Pallas TPU kernel: blocked multi-pair fragment-ANI window matching.
+"""Pallas TPU kernel: fragment-ANI window matching on device-resident planes.
 
 The exact-ANI refinement counts, per (query, reference) genome pair,
 how many of each query window's surviving k-mer hashes are members of
-the reference's sorted distinct-hash set. The XLA path is one
-`searchsorted` dispatch per pair (ops/fragment_ani.py::
-_window_match_counts_impl) — at campaign pair volumes the per-pair
-round trip dominates, the same wall PR 2's pairlist kernel removed
-from the screen. This module is the fragment-ANI twin: MULTIPLE pairs
-per grid launch, dense block compares on u32 hi/lo planes, int32
-per-element hit flags that the host folds into the identical
-per-window (matched, total) integers.
+the reference's sorted distinct-hash set. At MAG length a genome holds
+millions of such hashes, so what a pair costs is dominated by moving
+them: a launch that packs and uploads both sides per pair spends far
+longer on the host and the link than in the kernel. Here each
+profile's hashes are uploaded ONCE, as 32-bit hi/lo planes kept in a
+bounded device cache, and a pair's launch carries only its per-job
+row plan (two int32 scalars per 1024 query hashes).
 
-Membership without dynamic indexing (hardware-driven, like the
-pairlist kernel's design note): Mosaic rejects dynamic sublane loads
-on real v5e, and an in-kernel binary search is all dynamic gathers.
-Instead the HOST plans which reference blocks each query block can
-possibly hit — both sides are sorted, so query block j's values lie
-in [first_j, last_j] and only the reference blocks covering that value
-range (a `searchsorted` on the host, O(jobs log H)) need to be
-compared. Those block ids become a gather on the host; the kernel
-itself sees only static shapes and BlockSpec index maps:
+Membership: both sides are sorted, so query job j's values lie in
+[first_j, last_j] and only the reference rows (of 128 sorted hashes)
+holding values in that range can hit. The host plans them with one
+`searchsorted` per pair (O(jobs log H)) as a first and last row per
+job; the kernel reads the plan by scalar prefetch:
 
-  * JOB = one query block: QB = 8*128 = 1024 consecutive sorted query
-    elements in the dense kernels' transposed a-layout — element
-    l*8 + s of the job at row s, lane l of an (8, 128) u32 plane pair;
-  * each job scans SPAN consecutive gathered reference blocks of
-    RB = 8*128 = 1024 sorted elements in b-layout (8, 128) planes;
-    SPAN is the pow2-bucketed max over the launch's jobs, so the grid
-    is rectangular: grid = (jobs, SPAN), out block revisited across
-    the SPAN dim with an `@pl.when(s == 0)` init;
-  * gathered windows are SUPERSETS of the needed range — safe because
-    any extra block holds only values outside [first_j, last_j] (no
-    false hits) — and the padding block is a dedicated ALL-SENTINEL
-    block appended to the global block table, never a repeated real
-    block (a repeat would double-count an element's membership: the
-    reference set is distinct, so each element matches at most once
-    across distinct blocks).
+  * JOB = QB = 8*128 = 1024 consecutive sorted query hashes, row-major
+    in an (8, 128) int32 plane pair in VMEM, so the int8 flags come
+    back in the query's own element order;
+  * the grid is (jobs, SPAN): step s of job j brings reference block
+    first_j // 8 + s — an (8, 128) plane pair — into SMEM, SPAN being
+    the pow2 bucket of the widest job; steps past a job's own blocks
+    repeat its last block (no new DMA) and skip the compare;
+  * each needed reference hash is a scalar read from SMEM and compared
+    against the whole query block in two vector ops, OR-accumulated;
+    no lane broadcast, rotate or reduction is needed.
 
-u64 hashes are split into hi/lo u32 planes ON THE HOST (numpy), so no
-64-bit dtype ever reaches the kernel boundary (GL106). Sentinel-padded
-query tail slots (u32 planes both 0xFFFFFFFF) are masked in-kernel;
-sentinel reference slots can only equal sentinel queries, which that
-same mask removes.
+u64 hashes are split into hi/lo 32-bit planes ON THE HOST, so no 64-bit
+dtype reaches the kernel boundary (GL106); only equality is asked of
+them, so they travel as int32 bit patterns. Sentinel query tail slots
+(both planes all ones) are masked in-kernel; sentinel reference
+padding can only equal them.
 
-Pairs are packed by the caller into pow2-bucketed groups (padded
-window count, padded ref-set size — ops/fragment_ani.py's
-_bucket_pow2/pad_windows/pad_ref_set discipline) so launches compile
-a small (job-bucket x span-bucket) variant lattice. Per-element hit
-flags come back in element order; `fragment_ani` folds them with one
-`np.bincount` per pair into the per-window matched counts that flow
-unchanged through `_directed_from_counts_arrays` — DirectedANI floats
-bit-identical to the XLA and C paths (integer counts are exact, and
-the downstream f64 reduction is shared).
+Launches are dispatched asynchronously, a few in flight, so the host
+folds one pair's flags (one bincount per pair, in fragment_ani) while
+the device runs the next. Counts are exact integers, so DirectedANI
+floats stay bit-identical to the XLA and C paths.
 """
 
 from __future__ import annotations
 
-import os
-from typing import List, Optional, Sequence, Tuple
+import collections
+import weakref
+from typing import Iterator, Sequence, Tuple
 
 import numpy as np
 
@@ -67,35 +53,35 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from galah_tpu.obs.profile import profiled
-from galah_tpu.ops.constants import SENTINEL
 from galah_tpu.ops.pallas_pairwise import _zi
 from galah_tpu.utils import timing
 
 A_SUB = 8
 B_LANE = 128
 
-# Query elements per job: an (A_SUB, QLA) u32 plane pair in a-layout.
+# Query elements per job row: an (A_SUB, QLA) int32 plane pair.
 QLA = 128
 # Reference sublane rows per block: an (RSB, B_LANE) plane pair.
 RSB = 8
+_RSB_LOG2 = 3
+QB = A_SUB * QLA
+RB = RSB * B_LANE
 
-# Jobs per launch before the packer cuts a new launch: 2048 jobs is
-# 2M query elements (16 MB of u32 planes) — big enough that the grid
-# amortizes dispatch, small enough that the gathered reference planes
-# (jobs * span * RB * 8 B) stay bounded by _GATHER_BYTES_CAP below.
-LAUNCH_JOB_CAP = 2048
-
-# Host-side cap on one launch's gathered reference planes. The gather
-# duplicates blocks shared between jobs, so the bound is on the
-# DUPLICATED volume: job_slots * span * RB elements * 8 B/elem.
-_GATHER_BYTES_CAP = 256 << 20
-
-# Job-slot bucket floor: launches are padded to pow2 job counts so the
-# compile cache sees a small lattice, mirroring _bucket_pow2's role on
-# the window/ref axes.
+# Job-count bucket floor: a profile's query planes are padded to a pow2
+# job count so the compile cache sees a small lattice of grid sizes.
 _JOB_FLOOR = 8
 
-_U32_SENT = 0xFFFFFFFF
+# Device bytes the plane cache may hold. A 3.5 Mbp genome's query and
+# reference planes take ~64 MB, so this keeps ~90 genomes resident and
+# leaves most of a 16 GB chip to the stages that run before and after.
+PLANE_CACHE_BYTES = 6 << 30
+
+# Launches in flight before the oldest one's flags are fetched.
+PIPELINE_DEPTH = 4
+
+# Independent accumulators in the kernel's compare chain, so successive
+# compares do not wait on one another.
+_N_ACC = 4
 
 # Static kernel contract checked by `galah-tpu lint` (GL1xx):
 # representative bindings at the production geometry (QLA=128, RSB=8)
@@ -103,104 +89,114 @@ _U32_SENT = 0xFFFFFFFF
 PALLAS_CONTRACT = {
     "_window_hits_jit": {
         "bindings": {"qla": 128, "rsb": 8, "span": 2},
-        "in_dtypes": ["uint32", "uint32", "uint32", "uint32"],
+        "in_dtypes": ["int32", "int32", "int32", "int32", "int32",
+                      "int32"],
         "kernel_fns": ["_make_fragment_kernel"],
     },
 }
 
 # Numeric-determinism contract checked by `galah-tpu lint` (GL9xx):
 # per-element membership hits are exact integer counts; the kernel and
-# the XLA fallback must agree bit-for-bit for the same window packing.
+# the XLA path must agree bit-for-bit.
 DETERMINISM_CONTRACT = {
     "family": "fragment",
     "dtype": "int32",
-    "functions": ["window_element_hits", "_window_hits_jit"],
+    "functions": ["iter_element_hits", "_window_hits_jit"],
 }
 
 
-def fragment_pairs_per_launch() -> Optional[int]:
-    """Optional cap on pairs packed into one launch
-    (GALAH_TPU_FRAGMENT_PAIRS) — the bench sweep knob; unset means the
-    job/volume caps alone decide."""
-    raw = os.environ.get("GALAH_TPU_FRAGMENT_PAIRS", "")
-    if not raw:
-        return None
-    return max(1, int(raw))
-
-
 def _make_fragment_kernel(qla: int, rsb: int):
-    """Kernel body: one (job, span-step) program accumulating per-
-    element membership hits of an (A_SUB, qla) query block against an
-    (rsb, B_LANE) reference block."""
+    """Kernel body: one (job, span-step) program OR-accumulating per-
+    element membership flags of an (A_SUB, qla) query block against the
+    rows of one (rsb, B_LANE) reference block that the job needs.
 
-    def kernel(qh_ref, ql_ref, rh_ref, rl_ref, hits_ref):
+    The reference block sits in SMEM, so each of its hashes is a scalar
+    compared against the whole query block in two vector ops — no lane
+    broadcast, rotate or reduce."""
+
+    def kernel(first_ref, last_ref, qh_ref, ql_ref, rh_ref, rl_ref,
+               hits_ref):
+        j = pl.program_id(0)
         s = pl.program_id(1)
+        first = first_ref[j]
+        last = last_ref[j]
+        # shifts, not //: floor division promotes to int64 under x64
+        block = (first >> _RSB_LOG2) + s
 
         @pl.when(s == 0)
         def _init():
             hits_ref[...] = jnp.zeros_like(hits_ref)
 
-        qh = qh_ref[...]
-        ql = ql_ref[...]
-        sent = jnp.uint32(_U32_SENT)
-        valid = jnp.logical_not((qh == sent) & (ql == sent))
+        @pl.when(s <= (last >> _RSB_LOG2) - (first >> _RSB_LOG2))
+        def _scan():
+            qh = qh_ref[...]
+            ql = ql_ref[...]
+            sent = jnp.int32(-1)
+            valid = jnp.logical_not((qh == sent) & (ql == sent))
+            zero = jnp.zeros((A_SUB, qla), dtype=jnp.int32)
+            one = jnp.ones((A_SUB, qla), dtype=jnp.int32)
 
-        # Per query lane column: (A_SUB, 1) hi/lo against each of the
-        # reference block's (1, B_LANE) row chunks — every broadcast
-        # compare is one native (8, 128) vreg op. The reference set is
-        # distinct and scanned blocks are distinct, so each element
-        # hits at most once; summing lanes yields the 0/1 flag.
-        cols = []
-        for col in range(qla):
-            ch = qh[:, col:col + 1]
-            cl = ql[:, col:col + 1]
-            hit = jnp.zeros((A_SUB, B_LANE), dtype=jnp.int32)
-            for row in range(rsb):
-                rh = rh_ref[row:row + 1, :]
-                rl = rl_ref[row:row + 1, :]
-                hit = hit + ((ch == rh) & (cl == rl)).astype(jnp.int32)
-            cols.append(jnp.sum(hit, axis=1, keepdims=True,
-                                dtype=jnp.int32))
-        step = jnp.concatenate(cols, axis=1)
-        hits_ref[...] = hits_ref[...] + step * valid.astype(jnp.int32)
+            def row(r, accs):
+                accs = list(accs)
+                for lane in range(B_LANE):
+                    hit = (qh == rh_ref[r, lane]) & (ql == rl_ref[r, lane])
+                    k = lane % _N_ACC
+                    accs[k] = jnp.where(hit, one, accs[k])
+                return tuple(accs)
+
+            row0 = jnp.maximum(first - block * rsb, 0)
+            row1 = jnp.minimum(last - block * rsb, rsb - 1) + 1
+            accs = jax.lax.fori_loop(row0, row1, row, (zero,) * _N_ACC)
+            acc = accs[0]
+            for other in accs[1:]:
+                acc = acc | other
+            hits_ref[...] = hits_ref[...] | jnp.where(valid, acc, zero)
 
     return kernel
 
 
 def _window_hits_jit(
-    q_hi: jax.Array,   # uint32 (jobs*A_SUB, qla) a-layout query plane
+    first: jax.Array,  # int32 (jobs,) first reference row per job
+    last: jax.Array,   # int32 (jobs,) last reference row (-1: none)
+    q_hi: jax.Array,   # int32 (jobs*A_SUB, qla) row-major query plane
     q_lo: jax.Array,
-    r_hi: jax.Array,   # uint32 (jobs*span*rsb, B_LANE) gathered blocks
+    r_hi: jax.Array,   # int32 (blocks*rsb, B_LANE) reference planes
     r_lo: jax.Array,
     span: int,
     interpret: bool,
 ) -> jax.Array:
-    """One launch: per-element membership flags, int32 (jobs*A_SUB,
-    qla) in the query planes' layout."""
+    """One pair: per-element membership flags, int8 (jobs*A_SUB, qla)
+    in the query planes' (element) order. Job j scans reference rows
+    first[j] .. last[j], in span steps of one block each."""
     n_rows, qla = q_hi.shape
     n_jobs = n_rows // A_SUB
-    rsb = r_hi.shape[0] // max(n_jobs * span, 1)
-    kernel = _make_fragment_kernel(qla, rsb)
-    return pl.pallas_call(
-        kernel,
+
+    def q_map(j, s, first_ref, last_ref):
+        return (j, _zi(j))
+
+    def r_map(j, s, first_ref, last_ref):
+        f = first_ref[j] >> _RSB_LOG2
+        last_step = jnp.maximum((last_ref[j] >> _RSB_LOG2) - f, 0)
+        return (f + jnp.minimum(s, last_step), _zi(j))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(n_jobs, span),
         in_specs=[
-            pl.BlockSpec((A_SUB, qla), lambda j, s: (j, _zi(j)),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((A_SUB, qla), lambda j, s: (j, _zi(j)),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((rsb, B_LANE),
-                         lambda j, s, sp=span: (j * sp + s, _zi(j)),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((rsb, B_LANE),
-                         lambda j, s, sp=span: (j * sp + s, _zi(j)),
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((A_SUB, qla), q_map),
+            pl.BlockSpec((A_SUB, qla), q_map),
+            pl.BlockSpec((RSB, B_LANE), r_map, memory_space=pltpu.SMEM),
+            pl.BlockSpec((RSB, B_LANE), r_map, memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((A_SUB, qla), lambda j, s: (j, _zi(j)),
-                               memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((A_SUB, qla), q_map),
+    )
+    hits = pl.pallas_call(
+        _make_fragment_kernel(qla, RSB),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_rows, qla), jnp.int32),
         interpret=interpret,
-    )(q_hi, q_lo, r_hi, r_lo)
+    )(first, last, q_hi, q_lo, r_hi, r_lo)
+    return hits.astype(jnp.int8)
 
 
 _window_hits = profiled("fragment.window_hits")(
@@ -214,194 +210,171 @@ def _bucket_jobs(n: int) -> int:
     return b
 
 
-def _plan_pair(qh: np.ndarray, ref: np.ndarray,
-               n_rblocks: int) -> Tuple[int, np.ndarray, np.ndarray]:
-    """(n_jobs, lo_block, span) for one pair: which reference blocks
-    each query block's sorted value range can possibly hit. Computed
-    on the UNPADDED reference (padding is all-sentinel, above every
-    valid hash, so padded blocks never need scanning — but scanning
-    one as part of a pow2 window is harmless)."""
-    qb = A_SUB * QLA
-    rb = RSB * B_LANE
-    n_q = qh.shape[0]
-    n_jobs = -(-n_q // qb)
-    if n_jobs == 0:
-        z = np.zeros(0, dtype=np.int64)
-        return 0, z, z
-    firsts = qh[::qb]
-    last_idx = np.minimum(np.arange(1, n_jobs + 1) * qb, n_q) - 1
-    lasts = qh[last_idx]
-    lo = np.searchsorted(ref, firsts, side="left") // rb
-    hi = -(-np.searchsorted(ref, lasts, side="right") // rb)
-    hi = np.minimum(np.maximum(hi, lo + 1), max(n_rblocks, 1))
-    lo = np.minimum(lo, hi - 1)
-    return n_jobs, lo.astype(np.int64), (hi - lo).astype(np.int64)
-
-
-def _pow2_span(n: int) -> int:
+def _pow2(n: int) -> int:
     b = 1
     while b < n:
         b <<= 1
     return b
 
 
-def window_element_hits(
-    items: Sequence[Tuple[np.ndarray, np.ndarray, np.ndarray]],
-    interpret: bool = False,
-) -> "List[np.ndarray]":
-    """Per-element membership flags for many (query, reference) pairs.
-
-    `items[i]` is `(qh, ref, ref_padded)`: the pair's sorted surviving
-    query hashes (uint64, no sentinels — GenomeProfile.sorted_query()'s
-    first array), the reference's sorted distinct set, and its
-    sentinel-padded pow2 twin (GenomeProfile.padded_ref_set()).
-    Returns one int32 0/1 array per item, aligned to `qh`'s order —
-    `hits[e] == 1` iff `qh[e]` is a member of `ref`.
-
-    Pairs are packed into as few launches as the job/volume caps allow
-    (one Mosaic grid per launch covers every packed pair); reference
-    planes are deduplicated by profile identity before the per-job
-    block gather.
-    """
-    qb = A_SUB * QLA
-    rb = RSB * B_LANE
-    out: "List[Optional[np.ndarray]]" = [None] * len(items)
-
-    # live pairs only; empty queries hit nothing by definition
-    live: "List[int]" = []
-    plans = {}
-    for i, (qh, ref, ref_padded) in enumerate(items):
-        if qh.shape[0] == 0:
-            out[i] = np.zeros(0, dtype=np.int32)
-            continue
-        n_rblocks = ref_padded.shape[0] // rb
-        plans[i] = _plan_pair(qh, ref, n_rblocks)
-        live.append(i)
-
-    pair_cap = fragment_pairs_per_launch()
-    pos = 0
-    while pos < len(live):
-        # greedy launch packing under the job / gather-volume / pair
-        # caps; a single oversized pair still launches alone
-        end = pos
-        jobs_total = 0
-        span_max = 1
-        while end < len(live):
-            i = live[end]
-            n_jobs, _lo, span = plans[i]
-            nj = jobs_total + n_jobs
-            sp = max(span_max, _pow2_span(int(span.max())))
-            vol = _bucket_jobs(nj) * sp * rb * 8
-            if end > pos and (nj > LAUNCH_JOB_CAP
-                              or vol > _GATHER_BYTES_CAP
-                              or (pair_cap is not None
-                                  and end - pos >= pair_cap)):
-                break
-            jobs_total, span_max = nj, sp
-            end += 1
-        chunk = live[pos:end]
-        pos = end
-        _launch(items, plans, chunk, jobs_total, span_max, out,
-                interpret)
-    return out  # type: ignore[return-value]
+def _planes(vals: np.ndarray, n_slots: int,
+            width: int) -> Tuple[jax.Array, jax.Array]:
+    """Sorted u64 hashes as sentinel-padded (n_slots / width, width)
+    int32 hi/lo planes on the device (bit patterns: only equality is
+    ever asked of them; the sentinel is all ones in both)."""
+    words = np.ascontiguousarray(vals, dtype="<u8").view("<i4").reshape(
+        -1, 2)
+    hi = np.full(n_slots, -1, dtype=np.int32)
+    lo = np.full(n_slots, -1, dtype=np.int32)
+    hi[:vals.shape[0]] = words[:, 1]
+    lo[:vals.shape[0]] = words[:, 0]
+    return (jnp.asarray(hi.reshape(-1, width)),
+            jnp.asarray(lo.reshape(-1, width)))
 
 
-def _launch(items, plans, chunk, jobs_total, span, out,
-            interpret) -> None:
-    """Pack one launch's query/reference planes, run the kernel, and
-    scatter per-pair hit flags back into `out`."""
-    qb = A_SUB * QLA
-    rb = RSB * B_LANE
-    n_jobs_pad = _bucket_jobs(jobs_total)
+def _query_planes(qh: np.ndarray) -> Tuple[jax.Array, jax.Array]:
+    """Sorted query hashes as (jobs*A_SUB, QLA) planes, the job count
+    padded to its pow2 bucket."""
+    return _planes(qh, _bucket_jobs(-(-qh.shape[0] // QB)) * QB, QLA)
 
-    # global reference block table, deduplicated by profile identity
-    # (padded_ref_set() is cached per profile, so id() is stable);
-    # block G is the dedicated all-sentinel padding block
-    ref_base: "dict[int, int]" = {}
-    ref_parts: "List[np.ndarray]" = []
-    n_gblocks = 0
-    for i in chunk:
-        rp = items[i][2]
-        if id(rp) not in ref_base:
-            ref_base[id(rp)] = n_gblocks
-            ref_parts.append(rp)
-            n_gblocks += rp.shape[0] // rb
-    ref_cat = (np.concatenate(ref_parts) if ref_parts
-               else np.zeros(0, dtype=np.uint64))
-    g_hi = (ref_cat >> np.uint64(32)).astype(np.uint32).reshape(
-        n_gblocks, RSB, B_LANE)
-    g_lo = ref_cat.astype(np.uint32).reshape(n_gblocks, RSB, B_LANE)
-    sent_block = np.full((1, RSB, B_LANE), _U32_SENT, dtype=np.uint32)
-    g_hi = np.concatenate([g_hi, sent_block])
-    g_lo = np.concatenate([g_lo, sent_block])
-    sent_idx = n_gblocks
 
-    # per-job gathered block ids + the packed query planes
-    job_blocks = np.full((n_jobs_pad, span), sent_idx, dtype=np.int64)
-    q_cat = np.full(n_jobs_pad * qb, np.uint64(SENTINEL),
-                    dtype=np.uint64)
-    cursor = 0
-    spans_needed = 0
-    for i in chunk:
-        qh, _ref, rp = items[i]
-        n_jobs, lo, pair_span = plans[i]
-        n_rblocks = rp.shape[0] // rb
-        base = ref_base[id(rp)]
-        # window start: shift left so the pow2 window stays in range
-        # (superset scanning is safe; block REPETITION is not, so when
-        # span exceeds the reference the tail maps to the sentinel
-        # block instead of wrapping)
-        r0 = np.maximum(np.minimum(lo, n_rblocks - span), 0)
-        ids = r0[:, None] + np.arange(span, dtype=np.int64)[None, :]
-        rows = np.where(ids < n_rblocks, base + ids, sent_idx)
-        job_blocks[cursor:cursor + n_jobs] = rows
-        q_cat[cursor * qb:cursor * qb + qh.shape[0]] = qh
-        cursor += n_jobs
-        spans_needed += int(pair_span.sum())
+def _ref_planes(ref: np.ndarray) -> Tuple[jax.Array, jax.Array]:
+    """A sorted distinct reference set as (blocks*RSB, B_LANE) planes,
+    the block count padded to its pow2 bucket."""
+    return _planes(ref, _pow2(max(-(-ref.shape[0] // RB), 1)) * RB,
+                   B_LANE)
 
-    r_hi = g_hi[job_blocks.reshape(-1)].reshape(
-        n_jobs_pad * span * RSB, B_LANE)
-    r_lo = g_lo[job_blocks.reshape(-1)].reshape(
-        n_jobs_pad * span * RSB, B_LANE)
-    q_hi = (q_cat >> np.uint64(32)).astype(np.uint32).reshape(
-        n_jobs_pad, QLA, A_SUB).transpose(0, 2, 1).reshape(
-        n_jobs_pad * A_SUB, QLA)
-    q_lo = q_cat.astype(np.uint32).reshape(
-        n_jobs_pad, QLA, A_SUB).transpose(0, 2, 1).reshape(
-        n_jobs_pad * A_SUB, QLA)
+
+class _PlaneCache:
+    """Device planes of recently used host arrays, least recently used
+    evicted past a byte budget. Entries are keyed by the host array's
+    identity and hold it weakly: once a profile drops the array, its
+    planes go at the next lookup."""
+
+    def __init__(self, budget: int) -> None:
+        self.budget = budget
+        self._entries: "collections.OrderedDict[int, tuple]" = \
+            collections.OrderedDict()
+        self._bytes = 0
+
+    def _drop(self, key: int) -> None:
+        _ref, _planes, nbytes = self._entries.pop(key)
+        self._bytes -= nbytes
+
+    def get(self, arr: np.ndarray, build) -> Tuple[jax.Array, jax.Array]:
+        for key in [k for k, e in self._entries.items() if e[0]() is None]:
+            self._drop(key)
+        key = id(arr)
+        entry = self._entries.get(key)
+        if entry is not None and entry[0]() is arr:
+            self._entries.move_to_end(key)
+            return entry[1]
+        if entry is not None:
+            self._drop(key)
+        planes = build(arr)
+        nbytes = sum(int(p.nbytes) for p in planes)
+        timing.counter("fragment-pallas-uploads", 1)
+        self._entries[key] = (weakref.ref(arr), planes, nbytes)
+        self._bytes += nbytes
+        while self._bytes > self.budget and len(self._entries) > 1:
+            self._drop(next(iter(self._entries)))
+        return planes
+
+    def clear(self) -> None:
+        self._entries.clear()
+        self._bytes = 0
+
+
+_QUERY_CACHE = _PlaneCache(PLANE_CACHE_BYTES // 2)
+_REF_CACHE = _PlaneCache(PLANE_CACHE_BYTES // 2)
+
+
+def clear_plane_cache() -> None:
+    """Drop every cached device plane (tests; the start of each
+    cluster run)."""
+    _QUERY_CACHE.clear()
+    _REF_CACHE.clear()
+
+
+def _plan_pair(qh: np.ndarray, ref: np.ndarray, n_rblocks: int
+               ) -> Tuple[int, np.ndarray, np.ndarray]:
+    """(n_jobs, first_row, last_row) for one pair: the reference rows of
+    B_LANE sorted hashes that each query job's [first, last] value range
+    can hit. Computed on the UNPADDED reference (padding is
+    all-sentinel, above every valid hash); a job with no reference
+    values in range still names one row, so it scans one block."""
+    n_q = qh.shape[0]
+    n_jobs = -(-n_q // QB)
+    if n_jobs == 0:
+        z = np.zeros(0, dtype=np.int64)
+        return 0, z, z
+    n_rrows = max(n_rblocks, 1) * RSB
+    firsts = qh[::QB]
+    lasts = qh[np.minimum(np.arange(1, n_jobs + 1) * QB, n_q) - 1]
+    lo_e = np.searchsorted(ref, firsts, side="left")
+    hi_e = np.searchsorted(ref, lasts, side="right")
+    first = np.minimum(lo_e // B_LANE, n_rrows - 1)
+    last = np.clip((hi_e - 1) // B_LANE, first, n_rrows - 1)
+    return n_jobs, first, last
+
+
+def _dispatch(qh: np.ndarray, ref: np.ndarray,
+              interpret: bool) -> jax.Array:
+    """Plan one pair and launch its kernel (asynchronously)."""
+    q_hi, q_lo = _QUERY_CACHE.get(qh, _query_planes)
+    r_hi, r_lo = _REF_CACHE.get(ref, _ref_planes)
+    n_slots = q_hi.shape[0] // A_SUB
+    n_jobs, first, last = _plan_pair(qh, ref, r_hi.shape[0] // RSB)
+    first_p = np.zeros(n_slots, dtype=np.int32)
+    last_p = np.full(n_slots, -1, dtype=np.int32)
+    first_p[:n_jobs] = first
+    last_p[:n_jobs] = last
+    blocks = last // RSB - first // RSB + 1
+    span_max = _pow2(int(blocks.max()))
 
     timing.counter("fragment-pallas-launches", 1)
-    timing.counter("fragment-pallas-pairs", len(chunk))
-    timing.counter("fragment-pallas-jobs", jobs_total)
-    timing.counter("fragment-pallas-job-slots", n_jobs_pad)
-    timing.counter("fragment-pallas-ref-blocks", n_jobs_pad * span)
-    timing.counter("fragment-pallas-ref-blocks-needed", spans_needed)
+    timing.counter("fragment-pallas-pairs", 1)
+    timing.counter("fragment-pallas-jobs", n_jobs)
+    timing.counter("fragment-pallas-job-slots", n_slots)
+    timing.counter("fragment-pallas-ref-blocks", n_slots * span_max)
+    timing.counter("fragment-pallas-ref-blocks-needed", int(blocks.sum()))
+    timing.counter("fragment-pallas-ref-rows", int((last - first + 1).sum()))
     timing.dispatch()
-    hits = _window_hits(jnp.asarray(q_hi), jnp.asarray(q_lo),
-                        jnp.asarray(r_hi), jnp.asarray(r_lo),
-                        span=span, interpret=interpret)
-    timing.dispatch(sync=True)
-    flat = np.asarray(hits).reshape(
-        n_jobs_pad, A_SUB, QLA).transpose(0, 2, 1).reshape(-1)
+    return _window_hits(first_p, last_p, q_hi, q_lo, r_hi, r_lo,
+                        span=span_max, interpret=interpret)
 
-    from galah_tpu.obs import metrics as obs_metrics
 
-    obs_metrics.gauge(
-        "fragment.pallas_job_occupancy",
-        help="Real jobs / padded job slots in the last fragment-ANI "
-             "Pallas launch (pow2 job bucketing waste)",
-        unit="fraction").set(jobs_total / n_jobs_pad)
-    obs_metrics.gauge(
-        "fragment.pallas_span_occupancy",
-        help="Needed reference blocks / scanned reference blocks in "
-             "the last fragment-ANI Pallas launch (rectangular-span "
-             "padding waste)",
-        unit="fraction").set(
-        spans_needed / max(n_jobs_pad * span, 1))
+def iter_element_hits(
+    items: Sequence[Tuple[np.ndarray, np.ndarray]],
+    interpret: bool = False,
+) -> Iterator[Tuple[int, np.ndarray]]:
+    """(index, flags) per item, in item order, with up to
+    PIPELINE_DEPTH launches in flight.
 
-    cursor = 0
-    for i in chunk:
-        qh = items[i][0]
-        n_jobs = plans[i][0]
-        out[i] = flat[cursor * qb:cursor * qb + qh.shape[0]]
-        cursor += n_jobs
+    `items[i]` is `(qh, ref)`: the pair's sorted surviving query hashes
+    (uint64, no sentinels — GenomeProfile.sorted_query()'s first array)
+    and the reference's sorted distinct set (GenomeProfile.ref_set). The
+    same array objects across calls reuse their device planes. `flags`
+    is int8 0/1 aligned to `qh` — `flags[e] == 1` iff `qh[e]` is a
+    member of `ref`.
+    """
+    pending: "collections.deque" = collections.deque()
+
+    def fetch():
+        i, n_q, dev = pending.popleft()
+        flags = np.asarray(dev).reshape(-1)[:n_q]
+        if n_q:
+            timing.dispatch(sync=True)
+        return i, flags
+
+    for i, (qh, ref) in enumerate(items):
+        if qh.shape[0] == 0:
+            # empty queries hit nothing by definition
+            pending.append((i, 0, np.zeros(0, dtype=np.int8)))
+        else:
+            pending.append((i, qh.shape[0],
+                            _dispatch(qh, ref, interpret)))
+        if len(pending) > PIPELINE_DEPTH:
+            yield fetch()
+    while pending:
+        yield fetch()

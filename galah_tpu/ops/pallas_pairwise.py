@@ -47,6 +47,13 @@ A_SUB = 8     # a-chunk height: consecutive sketch values per lane column
 B_LANE = 128  # b-chunk width: consecutive sketch values per sublane row
 ROWS_PER_PROGRAM = 8
 
+#: Widest row the kernel is chosen for by default. Its compare loops are
+#: unrolled over (K/8) x (K/128) blocks, so compile time grows with K^2:
+#: K=1024 compiles for v5e in ~17 s, while the ~8k-wide marker rows of
+#: real MAGs had not compiled after 8 minutes. Wider rows take the XLA
+#: twin (7 s, 7.4 GB of temporaries at 8064 on v5e).
+MAX_DEFAULT_WIDTH = 1024
+
 # Static kernel contract checked by `galah-tpu lint` (GL1xx): bindings
 # are representative *maximum* values of the call-site locals the
 # BlockSpec shapes reference — k_pad=1024 (la = k_pad/A_SUB,
@@ -319,11 +326,9 @@ def tile_stats_pallas(
     ops/pairwise.tile_intersect_counts) and `total` the row's valid
     count.
 
-    range_skip is QUARANTINED (hardware-retired): the 2026-08-01
-    amortized on-chip campaign measured the skip variant 3.7x SLOWER
-    on the dense tile (218.1k -> 59.4k pairs/s at 512x512;
-    docs/artifacts/tpu_watch_20260801_0829/amortized.txt) — the
-    data-dependent window bounds defeat Mosaic's static scheduling.
+    range_skip is QUARANTINED: an early on-chip campaign measured the
+    skip variant 3.7x SLOWER on the dense tile (218.1k -> 59.4k
+    pairs/s at 512x512) — the data-dependent window bounds defeat Mosaic's static scheduling.
     No default path sets it; its parity tests run only in the
     slow/hardware tier. Kept as the reference windowed-compare
     formulation."""

@@ -18,12 +18,10 @@ ops/hashing's XLA preamble already builds with cheap shift/or chains;
 the kernel fuses the whole hash state machine — one block-elementwise
 pass, no u64 intermediates in HBM. Bit-identical to
 ops/hashing._murmur3_k21_1d (tests/test_pallas_sketch.py, interpret
-mode on CPU; tests/test_tpu_hw.py on hardware).
+mode on CPU).
 
-QUARANTINED — hardware-retired, kept for the record. The 2026-08-01
-amortized on-chip campaign measured this kernel at 0.06x the XLA u64
-emulation on the murmur core (docs/artifacts/tpu_watch_20260801_0829/
-amortized.txt): XLA's generic emulation fuses the constant multiplies
+QUARANTINED, kept for the record. An early on-chip campaign measured
+this kernel at 0.06x the XLA u64 emulation on the murmur core: XLA's generic emulation fuses the constant multiplies
 better than the 16-bit-limb schoolbook once the state machine is one
 elementwise pass. No default path selects it — activation requires
 BOTH hash_algo="murmur3" AND GALAH_TPU_PALLAS_HASH=1 (ops/hashing.py)

@@ -35,15 +35,12 @@ Strategy (GALAH_TPU_SKETCH_STRATEGY pin; unset resolves per backend):
   c     — csrc/sketch.c's host bottom-k sketcher, the historical
           single-device-CPU path.
 
-An explicit pin propagates failures (parity runs must never silently
-compare a fallback to itself); AUTO demotes fused -> xla once per
-process on a Mosaic failure, with a `sketch-fused-demoted` event.
+A kernel failure propagates under every strategy, pinned or AUTO.
 """
 
 from __future__ import annotations
 
 import os
-import threading
 import time
 from collections import deque
 from typing import Iterator, List, Optional, Sequence, Tuple
@@ -102,15 +99,6 @@ DETERMINISM_CONTRACT = {
                   "iter_path_sketches"],
 }
 
-# Concurrency contract, machine-checked by `galah-tpu lint` (GL8xx):
-# stage-2 packing and stage-1 ingest run on io/prefetch's shared pool
-# (its own GUARDED_BY covers the pool); this module's only shared
-# mutable state is the once-per-process fused demotion latch.
-GUARDED_BY = {
-    "_DEMOTED": "_DEMOTE_LOCK",
-}
-LOCK_ORDER = ["_DEMOTE_LOCK"]
-
 # Pipeline contract, machine-checked by `galah-tpu lint` (GL10xx):
 # these stages are generators that must stay streamed (GL1001/GL1002),
 # and this module feeds the occupancy gauge that proves the overlap
@@ -119,9 +107,6 @@ PIPELINE_STAGE = {
     "streaming": ["iter_path_sketches", "iter_sketch_row_blocks"],
     "occupancy_gauge": "workload.pipeline_occupancy",
 }
-
-_DEMOTE_LOCK = threading.Lock()
-_DEMOTED = False
 
 
 def _c_sketcher_available() -> bool:
@@ -212,17 +197,22 @@ _fused_group_sketch = profiled("sketch.fused")(jax.jit(
 def _pack_fused(genomes):
     """Stage-2 host transform: bucket + 2-bit pack the genomes into
     padded fused launch groups (the pallas_fragment recipe: pow2 job
-    count >= _JOB_FLOOR, pow2 block span; padding jobs are
-    all-ambiguous rows whose positions hash to the sentinel and never
-    enter the candidate file). Pure — safe on pool threads."""
+    count, pow2 length and block span; padding jobs are all-ambiguous
+    rows whose
+    positions hash to the sentinel and never enter the candidate file).
+    The job count is padded up to _JOB_FLOOR only while the launch stays
+    within FUSED_BUDGET positions: eight padded jobs of a 2 Mbp genome
+    already need more than a v5e chip's 16 GB of HBM. Pure — safe on
+    pool threads."""
     skipped, group_iter = hashing.iter_genome_groups(
-        genomes, budget=FUSED_BUDGET, max_len=DEFAULT_CHUNK)
+        genomes, budget=FUSED_BUDGET, max_len=DEFAULT_CHUNK, pow2=True)
     groups = []
     for chunk_idxs, packed, ambits, offs in group_iter:
         g = len(chunk_idxs)
         lb = packed.shape[1] * 4
         span = _pow2(lb // _BLOCK)
-        g_pad = _pow2(max(g, _JOB_FLOOR))
+        floor = max(1, min(_JOB_FLOOR, FUSED_BUDGET // lb))
+        g_pad = _pow2(max(g, floor))
         if g_pad > g:
             packed = np.vstack(
                 [packed, np.zeros((g_pad - g, packed.shape[1]),
@@ -329,25 +319,6 @@ def sketch_genomes_fused(
                                 k, seed, algo, interpret)
 
 
-def _demote_fused(err: Exception) -> None:
-    """Record the once-per-process fused->xla demotion."""
-    global _DEMOTED
-    with _DEMOTE_LOCK:
-        if _DEMOTED:
-            return
-        _DEMOTED = True
-    from galah_tpu.obs import events
-
-    timing.counter("sketch-fused-demoted", 1)
-    events.record("sketch-fused-demoted",
-                  error=f"{type(err).__name__}: {err}")
-
-
-def _fused_demoted() -> bool:
-    with _DEMOTE_LOCK:
-        return _DEMOTED
-
-
 def ingest_depth(threads: int) -> int:
     """Stage-1 look-ahead depth: GALAH_TPU_INGEST_DEPTH pin, else
     max(2, threads) — deep enough to keep `threads` parser workers
@@ -413,15 +384,13 @@ def _iter_staged(items: Iterator, stage_fn, depth: int = 2):
         prefetch._settle(fut for _, fut in pending)
 
 
-def _iter_fused_sketches(miss_iter, sketch_size, k, seed, algo,
-                         explicit):
+def _iter_fused_sketches(miss_iter, sketch_size, k, seed, algo):
     """(path, sketch) stream under the fused strategy: stage-2 packing
     double-buffered against stage-3 launches. The pack step is a pure
     host transform (iter_genome_groups' bucketing + 2-bit packing);
     the launch step runs the fused group dispatches on the consumer
     thread."""
     from galah_tpu.io import prefetch
-    from galah_tpu.ops._fallback import run_with_pallas_fallback
 
     interpret = jax.default_backend() != "tpu"
 
@@ -432,20 +401,8 @@ def _iter_fused_sketches(miss_iter, sketch_size, k, seed, algo,
         miss_iter, lambda g: g.codes.shape[0], FUSED_BUDGET)
     for buf, (skipped, groups) in _iter_staged(batches, pack, depth=2):
         gs = [g for _, g in buf]
-
-        def run(pallas: bool) -> List[MinHashSketch]:
-            if pallas:
-                return _sketch_packed_fused(
-                    gs, skipped, groups, sketch_size, k, seed, algo,
-                    interpret)
-            return sketch_genomes_device_batch(
-                gs, sketch_size=sketch_size, k=k, seed=seed, algo=algo)
-
-        use_fused = not _fused_demoted()
-        sketches, used = run_with_pallas_fallback(
-            "fused sketch kernel", explicit, use_fused, run)
-        if use_fused and not used:
-            _demote_fused(RuntimeError("Mosaic lowering failed"))
+        sketches = _sketch_packed_fused(gs, skipped, groups, sketch_size,
+                                        k, seed, algo, interpret)
         for (p, _g), s in zip(buf, sketches):
             yield p, s
 
@@ -480,14 +437,11 @@ def iter_path_sketches(
     from galah_tpu.resilience import dispatch as rdispatch
 
     if strategy is None:
-        strategy, explicit = resolve_sketch_strategy()
-    else:
-        explicit = True
+        strategy, _explicit = resolve_sketch_strategy()
     if strategy == "fused" and store.sketch_size > _CAND // 4:
         # candidate capacity cannot certify completeness at this
         # sketch_size — route to the exact batched path
         strategy = "xla"
-        explicit = False
     timing.counter(f"sketch-strategy-{strategy}", 1)
 
     t0 = time.monotonic()
@@ -529,7 +483,7 @@ def iter_path_sketches(
     if strategy == "fused":
         computed = _iter_fused_sketches(
             miss_iter, store.sketch_size, store.k, store.seed,
-            store.algo, explicit)
+            store.algo)
     elif strategy == "xla":
         def sketch_batch(buf):
             # Guarded device dispatch: retries transient failures and,

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from galah_tpu.obs.profile import profiled
-from galah_tpu.utils.jax_compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 logger = logging.getLogger(__name__)
@@ -261,7 +260,7 @@ def sharded_pair_count(
         local = jnp.sum(jax.lax.map(one_tile, jnp.arange(n_rt * n_ct)))
         return jax.lax.psum(local, "i")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(P("i", None), P(None, None)),
         out_specs=P(),
@@ -321,7 +320,7 @@ def sharded_stripe_stats(
         return (c.reshape(n_rt * row_tile, b),
                 t.reshape(n_rt * row_tile, b))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(P("i", None), P(None, None)),
         out_specs=(P("i", None), P("i", None)),
@@ -377,7 +376,7 @@ def sharded_stripe_stats_2d(
         return (cm.reshape(n_rt * row_tile, b),
                 tt.reshape(n_rt * row_tile, b))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(P("row", None), P("col", None)),
         out_specs=(P("row", "col"), P("row", "col")),
@@ -437,11 +436,9 @@ def _sharded_blocked_extract(
                 # pcast marks the constant zeros as device-varying so
                 # the cond branches type-check under shard_map's vma
                 # typing.
-                from galah_tpu.utils.jax_compat import pcast_varying
-
                 return tuple(
-                    pcast_varying(
-                        jnp.zeros((row_tile, col_tile), dt), "i")
+                    jax.lax.pcast(jnp.zeros((row_tile, col_tile), dt),
+                                  "i", to="varying")
                     for dt in stripe_dtypes)
 
             return jax.lax.cond(gt >= t_first, compute, skip, None)
@@ -471,7 +468,7 @@ def _sharded_blocked_extract(
         # all_gather result, identical on every device), but the vma
         # type system cannot express post-gather invariance for P()
         # out_specs (pcast has no varying->invariant direction).
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(lambda *a, cap: spmd(*a, cap), cap=cap),
             mesh=mesh,
             in_specs=in_specs,
@@ -549,12 +546,10 @@ def _sharded_blocked_extract_2d(
                 return tuple(compute_tile(col_arrs, rows_ctx, t))
 
             def skip(_):
-                from galah_tpu.utils.jax_compat import pcast_varying
-
                 return tuple(
-                    pcast_varying(pcast_varying(
+                    jax.lax.pcast(jax.lax.pcast(
                         jnp.zeros((row_tile, col_tile), dt),
-                        "row"), "col")
+                        "row", to="varying"), "col", to="varying")
                     for dt in stripe_dtypes)
 
             return jax.lax.cond(gt >= t_first, compute, skip, None)
@@ -592,7 +587,7 @@ def _sharded_blocked_extract_2d(
         # check_vma off for the same reason as the 1-D core: the
         # all_gather outputs ARE replicated but the vma type system
         # cannot express post-gather invariance for P() out_specs.
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(lambda *a, cap: spmd(*a, cap), cap=cap),
             mesh=mesh,
             in_specs=in_specs,
@@ -771,11 +766,11 @@ def sharded_screen_pairs(
     import math
 
     from galah_tpu.ops.constants import SENTINEL
-    from galah_tpu.ops.hll import use_pallas_default
-    from galah_tpu.ops.pairwise import tile_intersect_counts
+    from galah_tpu.ops.pairwise import (_marker_pallas_default,
+                                        tile_intersect_counts)
 
     if use_pallas is None:
-        use_pallas = use_pallas_default()
+        use_pallas = _marker_pallas_default(marker_mat.shape[1])
 
     n = marker_mat.shape[0]
     two_d = mesh_is_2d(mesh)

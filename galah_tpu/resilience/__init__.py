@@ -3,7 +3,7 @@ degradation, and bad-input quarantine.
 
 See docs/resilience.md for the operator-facing knobs. Import surface:
 
-  * policy — RetryPolicy, call_with_retry, the exception taxonomy
+  * policy — RetryPolicy, call_with_retry, the exception classes
   * faults — deterministic FaultInjector (GALAH_FI env grammar)
   * dispatch — the DispatchSupervisor every hot-path dispatch routes
     through (retry + validate + demote-to-fallback)

@@ -1,9 +1,11 @@
 """Shared ctypes builder/loader for the native C kernels.
 
 One implementation of the compile-on-first-import idiom used by
-io/_cingest.py, ops/_cpairstats.py, and ops/_csketch.py: mtime-checked
-rebuild, pid-suffixed temp + atomic os.replace (concurrent importers
-never dlopen a half-written library), and a process-wide failure cache
+io/_cingest.py, ops/_cpairstats.py, and ops/_csketch.py: each library
+is named by a hash of its source, compiler and flags, so a library
+built from other source (one copied in with a checkout, say) is never
+loaded; pid-suffixed temp + atomic os.replace (concurrent importers
+never dlopen a half-written library); and a process-wide failure cache
 so a broken toolchain or read-only package dir raises ImportError
 instantly on every retry instead of re-spawning the compiler per call
 (the caller modules are evicted from sys.modules when their import
@@ -16,6 +18,7 @@ JAX/numpy fallbacks catch.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import pathlib
 import subprocess
@@ -27,12 +30,22 @@ _SOSUFFIX = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
 _FAILED: dict[str, str] = {}
 
 
+def build_key(source: bytes, cc: str, extra_flags: tuple) -> str:
+    """The name a library built from `source` with `cc` and
+    `extra_flags` carries: any change to one of them names a new
+    library."""
+    h = hashlib.sha256(source)
+    for part in (cc, *extra_flags):
+        h.update(b"\0" + part.encode())
+    return h.hexdigest()[:16]
+
+
 def build_and_load(src_name: str, lib_stem: str, out_dir,
                    extra_flags: tuple = (),
                    disable_env: str | None = None) -> ctypes.CDLL:
-    """Compile csrc/<src_name> into <out_dir>/<lib_stem><EXT_SUFFIX>
-    (when stale) and dlopen it. Raises ImportError on any failure —
-    cached, so repeated attempts are cheap."""
+    """Compile csrc/<src_name> into <out_dir>/<lib_stem>-<hash><EXT_SUFFIX>
+    (unless that library exists) and dlopen it. Raises ImportError on
+    any failure — cached, so repeated attempts are cheap."""
     if disable_env and os.environ.get(disable_env):
         raise ImportError(f"native kernel disabled via {disable_env}")
     if src_name in _FAILED:
@@ -41,10 +54,11 @@ def build_and_load(src_name: str, lib_stem: str, out_dir,
         src = _CSRC / src_name
         if not src.is_file():
             raise ImportError(f"native source missing: {src}")
-        lib = pathlib.Path(out_dir) / f"{lib_stem}{_SOSUFFIX}"
-        if not (lib.is_file()
-                and lib.stat().st_mtime >= src.stat().st_mtime):
-            cc = os.environ.get("CC", "cc")
+        cc = os.environ.get("CC", "cc")
+        lib = pathlib.Path(out_dir) / (
+            f"{lib_stem}-{build_key(src.read_bytes(), cc, extra_flags)}"
+            f"{_SOSUFFIX}")
+        if not lib.is_file():
             tmp = lib.with_name(f"{lib.stem}.{os.getpid()}{lib.suffix}")
             cmd = [cc, "-O3", "-shared", "-fPIC", "-o", str(tmp),
                    str(src), *extra_flags]

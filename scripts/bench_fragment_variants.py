@@ -1,22 +1,21 @@
 """Per-strategy fragment-ANI throughput + packing-waste breakdown.
 
-BASELINE.md's ladder rungs put the exact-ANI refinement at ~half the
-end-to-end wall (rung-realistic-1000x3Mbp: 70-73 s of 145 s) with one
-XLA searchsorted dispatch per genome pair. This stage prices every
+Early ladder rungs put the exact-ANI refinement at ~half the
+end-to-end wall with one XLA searchsorted dispatch per genome pair.
+This stage prices every
 membership strategy (ops/fragment_ani._resolve_fragment_strategy) on
 the SAME synthetic pair list and decomposes the Pallas path's cost:
 
-  * pallas P sweep (GALAH_TPU_FRAGMENT_PAIRS = 1 / 8 / unset):
-    wall-clock through _directed_ani_batch_pallas — includes host
-    planning, packing, and the bincount fold, so it is the rate a
-    production run would see; the launch/job/span counters quantify
-    dispatch amortization and pow2 padding waste at each P;
+  * pallas: wall-clock through _directed_ani_batch_pallas — includes
+    host planning, plane uploads and the bincount fold, so it is the
+    rate a production run would see; the launch/job/span counters
+    quantify pow2 padding waste;
   * xla: the per-bucket vmapped-searchsorted path, same wall-clock
     protocol;
   * c merge: the compiled-C host path (skipped without the toolchain);
-  * kernel amortized: the bare _window_hits launch on pre-packed
+  * kernel amortized: the bare _window_hits launch on resident
     planes via bench_amortized's slope method — per-launch dispatch
-    cost and on-chip element rate with host packing excluded, so
+    cost and on-chip element rate with host work excluded, so
     (wall - kernel) isolates the host-side term.
 
 Self-budgeting like bench_pairlist_variants: variants run in priority
@@ -46,7 +45,8 @@ _T0 = time.monotonic()
 _COUNTERS = ("fragment-pallas-launches", "fragment-pallas-pairs",
              "fragment-pallas-jobs", "fragment-pallas-job-slots",
              "fragment-pallas-ref-blocks",
-             "fragment-pallas-ref-blocks-needed")
+             "fragment-pallas-ref-blocks-needed",
+             "fragment-pallas-ref-rows", "fragment-pallas-uploads")
 
 
 def _mutate(codes, rate, seed):
@@ -124,7 +124,6 @@ def main():
     # warm the per-profile caches outside any timed region
     for p in profiles:
         p.sorted_query()
-        p.padded_ref_set()
         p.padded_windows()
 
     def wall(fn, label, cost_s, extra=None):
@@ -167,22 +166,9 @@ def main():
             print(f"FAIL {label}: {type(e).__name__}: {e}", flush=True)
             results[label] = {"error": f"{type(e).__name__}: {e}"}
 
-    # --- pallas pack sweep: P caps launch packing; unset = auto ---
-    c_pal = 60 if interpret else 60
-    for p in (1, 8, None):
-        label = f"pallas P={'auto' if p is None else p}"
-
-        def run(p=p):
-            old = os.environ.pop("GALAH_TPU_FRAGMENT_PAIRS", None)
-            if p is not None:
-                os.environ["GALAH_TPU_FRAGMENT_PAIRS"] = str(p)
-            try:
-                fa._directed_ani_batch_pallas(pairs, 0.80, 0.5)
-            finally:
-                os.environ.pop("GALAH_TPU_FRAGMENT_PAIRS", None)
-                if old is not None:
-                    os.environ["GALAH_TPU_FRAGMENT_PAIRS"] = old
-        wall(run, label, c_pal)
+    # --- pallas: one launch per directed pair on resident planes ---
+    wall(lambda: fa._directed_ani_batch_pallas(pairs, 0.80, 0.5),
+         "pallas", 60)
 
     # --- xla vmapped searchsorted, same protocol ---
     wall(lambda: fa._directed_ani_batch_xla(pairs, 0.80, 0.5),
@@ -205,22 +191,14 @@ def main():
             rb = pf.RSB * pf.B_LANE
             q = np.sort(rng.integers(
                 0, 1 << 63, size=jobs * qb, dtype=np.uint64))
-            q_hi = jax.device_put(jnp.asarray(
-                (q >> np.uint64(32)).astype(np.uint32).reshape(
-                    jobs, pf.QLA, pf.A_SUB).transpose(0, 2, 1).reshape(
-                    jobs * pf.A_SUB, pf.QLA)))
-            q_lo = jax.device_put(jnp.asarray(
-                q.astype(np.uint32).reshape(
-                    jobs, pf.QLA, pf.A_SUB).transpose(0, 2, 1).reshape(
-                    jobs * pf.A_SUB, pf.QLA)))
             r = np.sort(rng.integers(
                 0, 1 << 63, size=jobs * span * rb, dtype=np.uint64))
-            r_hi = jax.device_put(jnp.asarray(
-                (r >> np.uint64(32)).astype(np.uint32).reshape(
-                    jobs * span * pf.RSB, pf.B_LANE)))
-            r_lo = jax.device_put(jnp.asarray(
-                r.astype(np.uint32).reshape(
-                    jobs * span * pf.RSB, pf.B_LANE)))
+            q_hi, q_lo = pf._query_planes(q)
+            r_hi, r_lo = pf._ref_planes(r)
+            # job j scans the rows of blocks j*span .. j*span+span-1
+            first_row = jnp.asarray(
+                np.arange(jobs, dtype=np.int32) * span * pf.RSB)
+            last_row = first_row + (span * pf.RSB - 1)
 
             def make_fn(reps):
                 @jax.jit
@@ -229,7 +207,7 @@ def main():
                         a, b, c, d = jax.lax.optimization_barrier(
                             (q_hi, q_lo, r_hi, r_lo))
                         h = pf._window_hits(
-                            a, b, c, d, span=span,
+                            first_row, last_row, a, b, c, d, span=span,
                             interpret=interpret)
                         return acc + jnp.sum(h, dtype=jnp.int32)
                     return jax.lax.fori_loop(
@@ -254,7 +232,7 @@ def main():
             results[label] = {"error": f"{type(e).__name__}: {e}"}
 
     # --- breakdown: host vs device split at the auto pack ---
-    auto = results.get("pallas P=auto", {})
+    auto = results.get("pallas", {})
     kern = results.get("kernel amortized", {})
     breakdown = {}
     if auto.get("us_per_pair") is not None:

@@ -1,7 +1,7 @@
 """Host FASTA ingestion at multi-Gbp: measure the named north-star risk.
 
-BASELINE.md's 50k-genome extrapolation names host-side FASTA ingestion
-(~175 Gbp) as "the open risk" on an assumed ~100 MB/s/core. This bench
+A 50k-genome extrapolation named host-side FASTA ingestion (~175 Gbp)
+as the open risk on an assumed ~100 MB/s/core. This bench
 replaces the assumption with measurements at real scale:
 
   1. single-thread C-parser throughput (csrc/ingest.c via

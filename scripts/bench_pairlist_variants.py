@@ -49,7 +49,7 @@ from bench_amortized import (  # noqa: E402
     _row,
 )
 
-HBM_BW = 8.1e11  # bytes/s, v5e spec sheet (BASELINE.md roofline)
+HBM_BW = 8.1e11  # bytes/s, v5e spec sheet
 _T0 = time.monotonic()
 
 

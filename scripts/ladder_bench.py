@@ -1,4 +1,4 @@
-"""BASELINE.md measurement ladder: end-to-end cluster() wall-clock.
+"""Measurement ladder: end-to-end cluster() wall-clock.
 
 Runs the first rungs of the BASELINE.json config ladder on the current
 backend (TPU via the default interpreter; CPU with --cpu) and prints a

@@ -61,7 +61,7 @@ def test_chaos_fleet_smoke(tmp_path):
     SIGKILL a worker group, SIGKILL the scheduler, SIGTERM the
     scheduler — each resumed and held to byte-identical clusters,
     zero debris, and a coherent reassignment chain (the full
-    10-iteration gate runs in scripts/tpu_validation_run.sh)."""
+    10-iteration gate is scripts/chaos_run.py --workload fleet)."""
     chaos_run = _load_chaos_run()
     failures = chaos_run.run_fleet_harness(iterations=3, seed=11,
                                            workdir=str(tmp_path),

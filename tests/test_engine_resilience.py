@@ -77,8 +77,28 @@ def test_persistent_ani_fault_demotes_and_completes():
     assert cl.pairs_computed
 
 
+def test_demotion_does_not_outlive_its_run():
+    """Demotions belong to one run: a site demoted in one cluster()
+    call is back on its primary path at the start of the next, so a
+    persistent fault in one run cannot reroute a later, healthy one."""
+    faults.install(FaultInjector([FaultSpec(
+        site="dispatch.ani", kind="raise", prob=1.0)]))
+    cluster(GENOMES, FakePre(), FakeCl(0.95))
+    assert [d.site for d in rdispatch.demotions()] == ["dispatch.ani"]
+
+    faults.reset()
+    calls = []
+    cl = FakeCl(0.95)
+    orig = cl.calculate_ani_batch
+    cl.calculate_ani_batch = lambda p: (calls.append(len(p)), orig(p))[1]
+    cluster(GENOMES, FakePre(), cl)
+    assert not rdispatch.demotions()
+    # batched primary dispatches, not the per-pair fallback loop
+    assert max(calls) > 1
+
+
 def test_device_lost_then_recovered():
-    """The tunnel-drop signature (DeviceLostError) is retryable too;
+    """The lost-device signature (DeviceLostError) is retryable too;
     one drop does not demote."""
     faults.install(FaultInjector([FaultSpec(
         site="dispatch.ani", kind="device-lost", prob=1.0,

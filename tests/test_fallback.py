@@ -1,91 +1,124 @@
-"""The shared explicit-pin / default-fallback policy for Mosaic kernels.
+"""No Mosaic kernel failure is swallowed on the default path.
 
-One helper (ops/_fallback.py) now carries the contract that four call
-sites (threshold_pairs, screen_pairs, hll_threshold_pairs, and the
-sparse pairlist batcher) previously duplicated: an explicitly pinned
-path fails loudly — parity tests must never vacuously compare XLA to
-XLA — while the default path downgrades to the XLA twin with a logged
-warning when Mosaic lowering fails.
+Every device extraction offers a Mosaic kernel with an XLA twin, and the
+Mosaic kernel is the default on a TPU backend. When that kernel fails,
+the failure propagates — pinned or chosen by default — so a broken chip
+path can never pass for a working one by quietly running its twin. Each
+case makes the default pick the Mosaic path, breaks the kernel below
+the former fallback gate, and expects the error.
 """
 
-import logging
-
+import numpy as np
 import pytest
 
-from galah_tpu.ops._fallback import run_with_pallas_fallback
+import jax
+
+from galah_tpu.ops import fragment_ani, hll, pairwise, sketch_stream
+from galah_tpu.ops import sparse_device
 
 
 class _Boom(RuntimeError):
     pass
 
 
-def test_pallas_success_returns_result_and_flag():
-    result, used = run_with_pallas_fallback(
-        "test kernel", explicit=False, use_pallas=True,
-        run=lambda p: ("ran", p))
-    assert result == ("ran", True)
-    assert used is True
+def _raise(*_a, **_k):
+    raise _Boom("injected Mosaic failure")
 
 
-def test_default_fallback_runs_xla_and_warns(caplog):
-    calls = []
+def _raise_on_pallas(position):
+    """A stand-in that fails only on the Mosaic path (use_pallas at
+    `position` or as a keyword) and returns an empty result otherwise,
+    so a fallback to the XLA twin would pass silently."""
 
-    def run(p):
-        calls.append(p)
-        if p:
-            raise _Boom("no lowering")
-        return "xla"
+    def fake(*args, **kwargs):
+        pallas = kwargs.get("use_pallas", args[position]
+                            if len(args) > position else False)
+        if pallas:
+            raise _Boom("injected Mosaic failure")
+        return {}
 
-    with caplog.at_level(logging.WARNING, "galah_tpu.ops._fallback"):
-        result, used = run_with_pallas_fallback(
-            "test kernel", explicit=False, use_pallas=True, run=run)
-    assert result == "xla"
-    assert used is False
-    assert calls == [True, False]
-    assert any("test kernel" in r.message and "falling back" in r.message
-               for r in caplog.records)
+    return fake
 
 
-def test_explicit_pin_propagates_failure():
-    def run(p):
-        raise _Boom("no lowering")
+def _sorted_rows(n=16, width=32, seed=0):
+    rng = np.random.default_rng(seed)
+    mat = rng.integers(0, 1 << 62, size=(n, width), dtype=np.uint64)
+    mat.sort(axis=1)
+    return mat
 
+
+def _threshold_pairs(monkeypatch):
+    monkeypatch.setattr(pairwise, "_threshold_pairs_single",
+                        _raise_on_pallas(6))
+    pairwise.threshold_pairs(_sorted_rows(), k=21, min_ani=0.9,
+                             row_tile=64)
+
+
+def _screen_pairs(monkeypatch):
+    monkeypatch.setattr(pairwise, "_screen_pairs_single",
+                        _raise_on_pallas(6))
+    pairwise.screen_pairs(_sorted_rows(), np.full(16, 32), 0.8,
+                          row_tile=64)
+
+
+def _hll_threshold_pairs(monkeypatch):
+    monkeypatch.setattr(hll, "_hll_threshold_single",
+                        _raise_on_pallas(5))
+    regs = np.zeros((16, 64), dtype=np.uint8)
+    hll.hll_threshold_pairs(regs, k=21, min_ani=0.9)
+
+
+def _pairlist_blocked(monkeypatch):
+    monkeypatch.setattr(sparse_device, "_batch_pair_stats",
+                        _raise_on_pallas(4))
+    pi = np.arange(8)
+    sparse_device.pair_stats_for_pairs(_sorted_rows(), pi, pi + 1, 32,
+                                       batch=4)
+
+
+def _pairlist_gather(monkeypatch):
+    monkeypatch.setenv("GALAH_TPU_PAIRLIST_STRATEGY", "gather")
+    monkeypatch.setattr(sparse_device, "_gather_tile_stats", _raise)
+    pi = np.arange(8)
+    sparse_device.pair_stats_for_pairs(_sorted_rows(), pi, pi + 1, 32)
+
+
+def _fragment(monkeypatch):
+    monkeypatch.setattr(fragment_ani, "_resolve_fragment_strategy",
+                        lambda *a, **k: ("pallas", False))
+    monkeypatch.setattr(fragment_ani, "_directed_ani_batch_pallas",
+                        _raise)
+    fragment_ani.directed_ani_batch([(None, None)])
+
+
+def _fused_sketch(monkeypatch):
+    monkeypatch.setattr(sketch_stream, "_pack_fused",
+                        lambda genomes: ([], []))
+    monkeypatch.setattr(sketch_stream, "_sketch_packed_fused", _raise)
+
+    class _G:
+        codes = np.zeros(100, dtype=np.uint8)
+
+    list(sketch_stream._iter_fused_sketches(
+        iter([("g.fna", _G())]), 1000, 21, 0, "murmur3"))
+
+
+SITES = {
+    "threshold_pairs": _threshold_pairs,
+    "screen_pairs": _screen_pairs,
+    "hll_threshold_pairs": _hll_threshold_pairs,
+    "pairlist_blocked": _pairlist_blocked,
+    "pairlist_gather": _pairlist_gather,
+    "fragment_window_hits": _fragment,
+    "fused_sketch": _fused_sketch,
+}
+
+
+@pytest.mark.parametrize("site", sorted(SITES))
+def test_default_mosaic_failure_propagates(site, monkeypatch):
+    monkeypatch.setattr(hll, "use_pallas_default", lambda: True)
+    # the single-device implementation: the test session's 8-device
+    # CPU mesh would otherwise route the AUTO path to the sharded twins
+    monkeypatch.setattr(jax, "device_count", lambda *a, **k: 1)
     with pytest.raises(_Boom):
-        run_with_pallas_fallback(
-            "test kernel", explicit=True, use_pallas=True, run=run)
-
-
-def test_use_pallas_false_skips_mosaic_entirely():
-    calls = []
-    result, used = run_with_pallas_fallback(
-        "test kernel", explicit=True, use_pallas=False,
-        run=lambda p: calls.append(p) or "xla")
-    assert result == "xla"
-    assert used is False
-    assert calls == [False]
-
-
-def test_xla_failure_always_propagates():
-    with pytest.raises(_Boom):
-        run_with_pallas_fallback(
-            "test kernel", explicit=False, use_pallas=False,
-            run=lambda p: (_ for _ in ()).throw(_Boom()))
-
-
-def test_downgrade_loop_pattern():
-    """The sparse batcher's loop: after one failure the returned flag
-    keeps later batches off the Mosaic path without retrying it."""
-    attempts = []
-
-    def run(p):
-        attempts.append(p)
-        if p:
-            raise _Boom()
-        return "xla"
-
-    use_pallas = True
-    for _ in range(3):
-        _, use_pallas = run_with_pallas_fallback(
-            "test kernel", explicit=False, use_pallas=use_pallas,
-            run=run)
-    assert attempts == [True, False, False, False]
+        SITES[site](monkeypatch)

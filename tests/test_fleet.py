@@ -160,6 +160,55 @@ def test_fleet_run_refuses_non_skani_methods(tmp_path, capsys):
 # -- scheduler (fake workers) ----------------------------------------
 
 
+def test_fleet_run_refuses_workers_sharing_an_accelerator(tmp_path,
+                                                         capsys):
+    """More than one worker on a non-CPU platform would put several
+    processes on this host's chips; the scheduler refuses before it
+    plans anything (and before any backend starts)."""
+    from galah_tpu.cli import main
+
+    p = str(tmp_path / "g0.fna")
+    with open(p, "w") as f:
+        f.write(">c1\n" + "ACGT" * 50 + "\n")
+    rc = main(["fleet", "--platform", "tpu", "run",
+               "--genome-fasta-files", p,
+               "--precluster-method", "skani",
+               "--cluster-method", "skani", "--workers", "2",
+               "--fleet-dir", str(tmp_path / "fleet"),
+               "--output-cluster-definition",
+               str(tmp_path / "clusters.tsv")])
+    assert rc == 1
+    assert "would share" in capsys.readouterr().err
+    assert not (tmp_path / "fleet").exists()
+
+
+@pytest.mark.parametrize("has_tpu,rc", [(False, 0), (True, 1)])
+def test_fleet_run_unpinned_platform_refuses_only_on_a_tpu_host(
+        tmp_path, monkeypatch, has_tpu, rc):
+    """With no platform pinned, JAX's default decides: several workers
+    are fine on a CPU-only host and refused where a TPU is present."""
+    from galah_tpu import cli
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.delenv("GALAH_TPU_PLATFORM", raising=False)
+    monkeypatch.setattr(cli, "_host_has_tpu", lambda: has_tpu)
+    args = cli.build_parser().parse_args(
+        ["fleet", "run", "--workers", "2", "--fleet-dir", str(tmp_path)])
+    monkeypatch.setattr("jax.config.update", lambda *a, **k: None)
+    assert cli._start_backend(args) == rc
+
+
+def test_fleet_workers_inherit_the_platform(tmp_path):
+    from galah_tpu.cli import _fleet_worker_argv, build_parser
+
+    args = build_parser().parse_args(
+        ["fleet", "--platform", "cpu", "run", "--fleet-dir",
+         str(tmp_path)])
+    spec = build_plan(["g.fna"], 1)[0]
+    argv = _fleet_worker_argv(args, str(tmp_path), "")(spec, False)
+    assert argv[argv.index("--platform") + 1] == "cpu"
+
+
 def _done_worker_argv(fleet_dir):
     """A fake worker that just leaves the merge artifact and exits 0."""
     def argv(spec, resume):

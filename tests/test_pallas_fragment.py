@@ -5,12 +5,13 @@ results side: per-element membership flags identical to numpy's
 definition over a bucket-boundary lattice, per-window matched counts
 bit-identical to the XLA searchsorted and compiled-C merge strategies,
 and DirectedANI / cluster compositions byte-for-byte equal under every
-GALAH_TPU_FRAGMENT_STRATEGY pin. The packing contract (ONE launch per
-pow2-bucketed shape group, pair cap honored) is pinned through the
-timing counters the bench stage reads.
+GALAH_TPU_FRAGMENT_STRATEGY pin. The launch contract (one launch per
+directed pair, each profile's planes uploaded once, the device cache
+bounded) is pinned through the timing counters.
 
-All kernel executions here run interpret=True (CPU container); the
-hardware suite re-runs the lattice on a real chip via test_tpu_hw.
+All kernel executions here run interpret=True on the CPU; the kernel
+compiles for v5e in tests/test_tpu_compile.py and runs on the chip in
+chip_smoke.py.
 """
 
 import logging
@@ -36,6 +37,13 @@ def _genome(codes, name):
                   stats=GenomeStats(1, int((codes == 255).sum()), n))
 
 
+def _element_hits(items):
+    """Per-item 0/1 flags, in item order, from the interpret-mode
+    kernel."""
+    return [flags.astype(np.int32)
+            for _i, flags in pf.iter_element_hits(items, interpret=True)]
+
+
 def _counter_delta(before, after):
     return {k: after.get(k, 0) - before.get(k, 0)
             for k in set(before) | set(after)
@@ -48,9 +56,8 @@ def _counter_delta(before, after):
 def test_kernel_hits_match_numpy_membership_lattice():
     """Per-element flags == np.isin over job/ref pow2 boundaries,
     duplicates, empty sides, all-hit and no-hit extremes — every item
-    packed into the SAME window_element_hits call so the multi-pair
-    launch path (dedup'd block table, sentinel padding block, superset
-    windows) is what gets exercised."""
+    in the SAME iter_element_hits call, so pipelined launches and a
+    reference shared between items are what gets exercised."""
     rng = np.random.default_rng(11)
     qb = pf.A_SUB * pf.QLA          # 1024: the job quantum
     rb = pf.RSB * pf.B_LANE         # 1024: the ref block quantum
@@ -76,23 +83,28 @@ def test_kernel_hits_match_numpy_membership_lattice():
     cases.append((dup, ref_small))
     cases.append((np.sort(u64s(500) | np.uint64(1 << 63)),
                   ref_small))                                # no hit
-    # two items SHARING one padded ref (block-table dedup path)
-    shared = fa.pad_ref_set(ref_edge)
-    items = [(qh, ref, fa.pad_ref_set(ref)) for qh, ref in cases]
-    items.append((np.sort(u64s(700)), ref_edge, shared))
-    items.append((np.sort(u64s(900)), ref_edge, shared))
+    # every case its own reference array, except two items SHARING one
+    # (uploaded once)
+    items = [(qh, ref.copy()) for qh, ref in cases]
+    shared = ref_edge.copy()
+    items.append((np.sort(u64s(700)), shared))
+    items.append((np.sort(u64s(900)), shared))
 
     before = timing.GLOBAL.counters()
-    hits = pf.window_element_hits(items, interpret=True)
+    hits = _element_hits(items)
     delta = _counter_delta(before, timing.GLOBAL.counters())
 
-    for (qh, ref, _rp), h in zip(items, hits):
+    for (qh, ref), h in zip(items, hits):
         expect = np.isin(qh, ref).astype(np.int32)
         np.testing.assert_array_equal(h, expect)
-    # every live item packs into one launch (jobs far below the cap);
-    # the empty-query item short-circuits without a job slot
-    assert delta.get("fragment-pallas-launches") == 1
-    assert delta.get("fragment-pallas-pairs") == len(items) - 1
+    # one launch per live item; the empty-query item short-circuits
+    # without one, and the shared reference is uploaded once
+    live = [it for it in items if it[0].shape[0]]
+    assert delta.get("fragment-pallas-launches") == len(live)
+    assert delta.get("fragment-pallas-pairs") == len(live)
+    refs = {id(ref) for _q, ref in live}
+    assert len(refs) == len(live) - 1
+    assert delta.get("fragment-pallas-uploads") == len(live) + len(refs)
 
 
 def test_kernel_sentinel_queries_never_match():
@@ -104,8 +116,7 @@ def test_kernel_sentinel_queries_never_match():
     qh = np.concatenate([ref[:10],
                          np.full(5, np.uint64(SENTINEL))])
     qh = np.sort(qh)
-    (h,) = pf.window_element_hits(
-        [(qh, ref, fa.pad_ref_set(ref))], interpret=True)
+    (h,) = _element_hits([(qh, ref)])
     np.testing.assert_array_equal(h, np.isin(qh, ref).astype(np.int32))
     assert int(h.sum()) == 10
 
@@ -151,6 +162,7 @@ def strategy_results(pairs):
     """Each strategy's DirectedANI list over the same pairs, plus the
     pallas run's launch-counter deltas (the dispatch-count acceptance
     evidence) — computed once for the whole module."""
+    pf.clear_plane_cache()
     before = timing.GLOBAL.counters()
     res = {"pallas": fa._directed_ani_batch_pallas(pairs, FLOOR, FRAC)}
     counters = _counter_delta(before, timing.GLOBAL.counters())
@@ -167,9 +179,8 @@ def test_per_window_counts_bit_identical(profiles):
     sel = [(profiles[0], profiles[1]), (profiles[1], profiles[0]),
            (profiles[4], profiles[0]), (profiles[3], profiles[5]),
            (profiles[5], profiles[3])]
-    items = [(q.sorted_query()[0], r.ref_set, r.padded_ref_set())
-             for q, r in sel]
-    hits = pf.window_element_hits(items, interpret=True)
+    items = [(q.sorted_query()[0], r.ref_set) for q, r in sel]
+    hits = _element_hits(items)
     for (q, r), h in zip(sel, hits):
         qh, qw, totals = q.sorted_query()
         w = q.n_windows
@@ -187,6 +198,18 @@ def test_per_window_counts_bit_identical(profiles):
             np.testing.assert_array_equal(pallas_m, np.asarray(cm))
 
 
+def test_flag_fold_matches_bincount(profiles):
+    """The host fold of a pair's flags (C where built) gives numpy's
+    per-window counts."""
+    q, r = profiles[1], profiles[0]
+    qh, qw, totals = q.sorted_query()
+    (flags,) = [f for _i, f in pf.iter_element_hits([(qh, r.ref_set)],
+                                                     interpret=True)]
+    np.testing.assert_array_equal(
+        fa._fold_flags(flags, qw, totals.shape[0]),
+        np.bincount(qw[flags != 0], minlength=totals.shape[0]))
+
+
 def test_directed_ani_bit_identical_across_strategies(strategy_results):
     res, _ = strategy_results
     assert len(res) >= 2
@@ -199,32 +222,35 @@ def test_directed_ani_bit_identical_across_strategies(strategy_results):
             assert a == b, (name, i, a, b)
 
 
-def test_one_launch_per_shape_group(pairs, strategy_results):
-    """Acceptance: the pallas path dispatches ONE kernel launch per
-    pow2-bucketed shape group, not one per pair."""
+def test_one_launch_per_pair_planes_uploaded_once(profiles, pairs,
+                                                  strategy_results):
+    """Acceptance: one launch per directed pair, and each profile's
+    query and reference planes cross to the device once for all of
+    them."""
     _, counters = strategy_results
-    groups = {(q.padded_windows().shape, r.padded_ref_set().shape[0],
-               q.k, q.fraglen, q.subsample_c) for q, r in pairs}
-    assert counters["fragment-pallas-launches"] == len(groups)
-    assert len(groups) < len(pairs)
+    assert counters["fragment-pallas-launches"] == len(pairs)
     assert counters["fragment-pallas-pairs"] == len(pairs)
+    assert counters["fragment-pallas-uploads"] == 2 * len(profiles)
     assert counters["fragment-pallas-jobs"] <= \
         counters["fragment-pallas-job-slots"]
     assert counters["fragment-pallas-ref-blocks-needed"] <= \
         counters["fragment-pallas-ref-blocks"]
 
 
-def test_pair_cap_splits_launches(pairs, monkeypatch):
-    """GALAH_TPU_FRAGMENT_PAIRS=1 degenerates packing to one launch
-    per pair — and the results stay identical."""
-    sub = pairs[:3]
-    monkeypatch.setenv("GALAH_TPU_FRAGMENT_PAIRS", "1")
+def test_plane_cache_budget_evicts(profiles, monkeypatch):
+    """A device cache too small for two planes uploads each profile's
+    planes again once evicted — and the results stay identical."""
+    sub = [(profiles[0], profiles[1]), (profiles[1], profiles[2]),
+           (profiles[2], profiles[0])]
+    pf.clear_plane_cache()
+    for cache in (pf._QUERY_CACHE, pf._REF_CACHE):
+        monkeypatch.setattr(cache, "budget", 1)
     before = timing.GLOBAL.counters()
-    capped = fa._directed_ani_batch_pallas(sub, FLOOR, FRAC)
+    tight = fa._directed_ani_batch_pallas(sub, FLOOR, FRAC)
     delta = _counter_delta(before, timing.GLOBAL.counters())
-    assert delta["fragment-pallas-launches"] == len(sub)
-    monkeypatch.delenv("GALAH_TPU_FRAGMENT_PAIRS")
-    assert capped == fa._directed_ani_batch_pallas(sub, FLOOR, FRAC)
+    assert delta["fragment-pallas-uploads"] == 2 * len(sub)
+    monkeypatch.undo()
+    assert tight == fa._directed_ani_batch_pallas(sub, FLOOR, FRAC)
 
 
 def test_zero_window_query_parity(profiles):
@@ -297,30 +323,27 @@ def _broken_kernel(*_a, **_k):
     raise RuntimeError("forced fragment kernel failure")
 
 
-def test_auto_pallas_failure_demotes_to_xla(pairs, monkeypatch, caplog):
-    """AUTO-chosen pallas that fails at runtime demotes to the XLA
-    twin (identical results), counts the demotion, and warns — it must
-    never take down a production run."""
+def test_auto_pallas_failure_propagates(pairs, monkeypatch):
+    """AUTO-chosen pallas that fails at runtime propagates the failure:
+    no quiet rerun on the XLA twin, no demotion counter."""
     sub = pairs[:3]
     monkeypatch.delenv("GALAH_TPU_FRAGMENT_STRATEGY", raising=False)
     monkeypatch.setattr(fa, "_resolve_fragment_strategy",
                         lambda *a, **k: ("pallas", False))
-    monkeypatch.setattr(pf, "window_element_hits", _broken_kernel)
+    monkeypatch.setattr(pf, "iter_element_hits", _broken_kernel)
     before = timing.GLOBAL.counters()
-    with caplog.at_level(logging.WARNING, logger="galah_tpu.ops._fallback"):
-        got = fa.directed_ani_batch(sub, FLOOR, FRAC)
+    with pytest.raises(RuntimeError, match="forced fragment kernel"):
+        fa.directed_ani_batch(sub, FLOOR, FRAC)
     delta = _counter_delta(before, timing.GLOBAL.counters())
-    assert got == fa._directed_ani_batch_xla(sub, FLOOR, FRAC)
-    assert delta.get("fragment-pallas-demoted") == 1
-    assert any("fragment window-match kernel" in r.getMessage()
-               for r in caplog.records)
+    assert delta.get("fragment-strategy-pallas") == 1
+    assert "fragment-pallas-demoted" not in delta
 
 
 def test_explicit_pin_propagates_kernel_failure(pairs, monkeypatch):
     """A pinned pallas run must fail loudly — parity captures must
     never silently compare the fallback to itself."""
     monkeypatch.setenv("GALAH_TPU_FRAGMENT_STRATEGY", "pallas")
-    monkeypatch.setattr(pf, "window_element_hits", _broken_kernel)
+    monkeypatch.setattr(pf, "iter_element_hits", _broken_kernel)
     with pytest.raises(RuntimeError, match="forced fragment"):
         fa.directed_ani_batch(pairs[:2], FLOOR, FRAC)
 

@@ -1,6 +1,6 @@
 """Mosaic pairlist kernel: bit-parity with the XLA pair stats, in
-interpreter mode on the CPU test mesh (hardware lowering is covered by
-tests/test_tpu_hw.py)."""
+interpreter mode on the CPU test mesh (the v5e compile is covered by
+tests/test_tpu_compile.py)."""
 
 import numpy as np
 import pytest

@@ -1,10 +1,8 @@
 """Mosaic murmur3 sketch kernel: bit-parity with the XLA hash core,
-run in interpreter mode on the CPU test mesh (hardware lowering is
-covered by tests/test_tpu_hw.py).
+run in interpreter mode on the CPU test mesh.
 
-Whole module is slow-tier: the kernel is QUARANTINED (hardware-retired
-at 0.06x XLA, docs/artifacts/tpu_watch_20260801_0829/amortized.txt;
-see ops/pallas_sketch.py) and reachable only via the
+Whole module is slow-tier: the kernel is QUARANTINED (measured on a
+chip at 0.06x XLA; see ops/pallas_sketch.py) and reachable only via the
 GALAH_TPU_PALLAS_HASH opt-in, so its parity no longer gates the
 default per-commit loop."""
 
@@ -91,8 +89,8 @@ def test_env_optin_end_to_end_sketch_identical(monkeypatch):
 
 def test_tail_word_high_bytes_ignored():
     """The contract uses only the low 5 bytes of the tail word; bytes
-    5-7 must not affect the hash (tests/test_tpu_hw.py feeds
-    full-random words and relies on this)."""
+    5-7 must not affect the hash (callers may feed full-random
+    words)."""
     rng = np.random.default_rng(13)
     n = 256
     k1 = jnp.asarray(rng.integers(0, 1 << 64, size=n, dtype=np.uint64))

@@ -1,7 +1,7 @@
 """Cross-run perf ledger tests (galah_tpu/obs/ledger.py + `perf` CLI).
 
 Covers the JSONL record/history/check round-trip, the median±MAD
-verdict taxonomy (regression, improvement, drift, insufficient
+verdict classes (regression, improvement, drift, insufficient
 history), torn-tail recovery after a mid-append crash, key isolation
 across device topologies, report-driven entry construction, and the
 jax-free `galah-tpu perf` subcommand including the --soft CI mode.
@@ -114,7 +114,7 @@ def test_metric_direction_families():
     assert ledger.metric_direction("flow.pairs.share") == "neutral"
 
 
-# -- check(): verdict taxonomy ---------------------------------------
+# -- check(): verdict classes ----------------------------------------
 
 
 def test_check_ok_on_unchanged_history():

@@ -87,7 +87,7 @@ def test_from_env_overrides(monkeypatch):
     assert RetryPolicy.from_env(max_attempts=2).max_attempts == 2
 
 
-def test_is_retryable_taxonomy():
+def test_is_retryable_classes():
     assert is_retryable(TransientDispatchError("x"))
     assert is_retryable(DeviceLostError("x"))
     assert is_retryable(GarbageResultError("x"))

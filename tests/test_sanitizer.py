@@ -11,6 +11,7 @@ import types
 import pytest
 
 from galah_tpu.analysis import sanitizer
+from galah_tpu.analysis.concurrency_check import THREADED_MODULES
 from galah_tpu.analysis.sanitizer import (SanDict, SanList, SanLock,
                                           Sanitizer)
 
@@ -371,7 +372,7 @@ def test_repo_runs_violation_free_under_sanitizer():
     errs = sanitizer.GLOBAL.errors()
     assert errs == [], json.dumps(errs, indent=1)
     s = sanitizer.GLOBAL.summary()
-    assert s["modules"] == 15  # == len(THREADED_MODULES)
+    assert s["modules"] == len(THREADED_MODULES)
     assert s["acquisitions"] > 0
     assert (s["undeclared_acquisitions"] == s["undeclared_edges"]
             == s["inversions"] == s["races"] == 0)

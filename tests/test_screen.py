@@ -189,3 +189,42 @@ print("OK")
                           cwd=repo, env=env)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "OK" in proc.stdout
+
+
+def test_marker_screen_picks_mosaic_only_for_widths_it_compiles(
+        monkeypatch):
+    """On a TPU backend the marker screen defaults to the Mosaic kernel
+    only up to MAX_DEFAULT_WIDTH markers per row: the kernel's compile
+    time grows with the square of the width, and real MAGs carry ~8k
+    markers. Wider rows take the host collision screen, not the XLA
+    twin; off the TPU the dense screen stays the default."""
+    import galah_tpu.ops.hll as hll
+    from galah_tpu.ops.pairwise import (_dense_marker_screen_default,
+                                        _marker_pallas_default)
+    from galah_tpu.ops.pallas_pairwise import MAX_DEFAULT_WIDTH
+
+    monkeypatch.setattr(hll, "use_pallas_default", lambda: True)
+    assert _marker_pallas_default(MAX_DEFAULT_WIDTH)
+    assert not _marker_pallas_default(MAX_DEFAULT_WIDTH + 64)
+    assert _dense_marker_screen_default(MAX_DEFAULT_WIDTH)
+    assert not _dense_marker_screen_default(MAX_DEFAULT_WIDTH + 64)
+    monkeypatch.setattr(hll, "use_pallas_default", lambda: False)
+    assert not _marker_pallas_default(64)
+    assert _dense_marker_screen_default(MAX_DEFAULT_WIDTH + 64)
+
+
+def test_wide_markers_on_tpu_take_the_host_collision_screen(monkeypatch):
+    """Rows wider than the Mosaic kernel compiles for, on a TPU, are
+    screened on the host: same pairs as numpy, no device tile."""
+    import galah_tpu.ops.hll as hll
+    from galah_tpu.ops.pallas_pairwise import MAX_DEFAULT_WIDTH
+    from galah_tpu.utils import timing
+
+    mat, counts = _marker_fixture(n=40, m=MAX_DEFAULT_WIDTH + 64)
+    monkeypatch.setattr(hll, "use_pallas_default", lambda: True)
+    monkeypatch.setattr(pairwise, "_screen_pairs_single", None)
+    before = timing.GLOBAL.counters().get("screen-candidates", 0)
+    got = pairwise.screen_pairs(mat, counts, 0.5)
+    assert timing.GLOBAL.counters().get("screen-candidates", 0) > before
+    assert sorted(got) == _numpy_screen(mat, counts, 0.5)
+    assert (3, 12) in got
