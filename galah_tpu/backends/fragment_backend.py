@@ -128,6 +128,31 @@ class ProfileStore:
             "markers": prof.markers,
         })
 
+    def _read(self, path: str):
+        """One genome's FASTA read: the span `profile.read`."""
+        with timing.stage("profile.read"):
+            return read_genome(path)
+
+    def _build(self, genome) -> GenomeProfile:
+        """One genome's profile: the span `profile.build`; its bases
+        count in `profile-bp`."""
+        timing.counter("profile-bp", genome.length)
+        with timing.stage("profile.build"):
+            return fragment_ani.build_profile(
+                genome, k=self.k, fraglen=self.fraglen,
+                subsample_c=self.subsample_c,
+                hash_algorithm=self.hash_algorithm)
+
+    def _build_batch(self, genomes) -> "list[GenomeProfile]":
+        """A group's profiles in grouped hash dispatches: one
+        `profile.build` span for the group."""
+        timing.counter("profile-bp", sum(g.length for g in genomes))
+        with timing.stage("profile.build"):
+            return fragment_ani.build_profiles_batch(
+                genomes, k=self.k, fraglen=self.fraglen,
+                subsample_c=self.subsample_c,
+                hash_algorithm=self.hash_algorithm)
+
     def get(self, path: str) -> GenomeProfile:
         prof = self._cache.get(path)
         if prof is not None:
@@ -135,10 +160,7 @@ class ProfileStore:
             return prof
         prof = self._load_disk(path)
         if prof is None:
-            prof = fragment_ani.build_profile(
-                read_genome(path), k=self.k, fraglen=self.fraglen,
-                subsample_c=self.subsample_c,
-                hash_algorithm=self.hash_algorithm)
+            prof = self._build(self._read(path))
             self._store_disk(path, prof)
         self._insert(path, prof)
         return prof
@@ -168,18 +190,12 @@ class ProfileStore:
         # hashing batches
         batched = not fragment_ani._c_profile_available(self.k)
         for p, prof in process_stream(
-                iter_prefetched(misses, read_genome,
+                iter_prefetched(misses, self._read,
                                 depth=max(2, self.threads)),
                 lambda g: g.codes.shape[0],
                 fragment_ani.PROFILE_BATCH_BUDGET,
-                lambda buf: fragment_ani.build_profiles_batch(
-                    [g for _, g in buf], k=self.k, fraglen=self.fraglen,
-                    subsample_c=self.subsample_c,
-                    hash_algorithm=self.hash_algorithm),
-                lambda _path, g: fragment_ani.build_profile(
-                    g, k=self.k, fraglen=self.fraglen,
-                    subsample_c=self.subsample_c,
-                    hash_algorithm=self.hash_algorithm),
+                lambda buf: self._build_batch([g for _, g in buf]),
+                lambda _path, g: self._build(g),
                 batched=batched, workers=self.threads):
             self._store_disk(p, prof)
             self._insert(p, prof)
@@ -402,9 +418,10 @@ class SkaniPreclusterer(PreclusterBackend):
                         [genome_paths[g] for g in missing])))
             prof.update(
                 (g, warm[g]) for g in endpoints if g in warm)
-            return _guarded_ani_values(
-                [(prof[i], prof[j]) for i, j in my_pairs],
-                self.min_aligned_fraction, self.store.threads)
+            with timing.stage("fragment-ani"):
+                return _guarded_ani_values(
+                    [(prof[i], prof[j]) for i, j in my_pairs],
+                    self.min_aligned_fraction, self.store.threads)
 
         return distributed.sharded_optional_floats(
             len(pairs), compute_mine, owner=lambda k: pairs[k][1])
@@ -448,9 +465,10 @@ class SkaniPreclusterer(PreclusterBackend):
                     if ani is not None and ani >= self.threshold:
                         cache.insert((i, j), float(ani))
         else:
-            anis = _guarded_ani_values(
-                [(profiles[i], profiles[j]) for i, j in pairs],
-                self.min_aligned_fraction, self.store.threads)
+            with timing.stage("fragment-ani"):
+                anis = _guarded_ani_values(
+                    [(profiles[i], profiles[j]) for i, j in pairs],
+                    self.min_aligned_fraction, self.store.threads)
             for (i, j), ani in zip(pairs, anis):
                 if ani is not None and ani >= self.threshold:
                     cache.insert((i, j), ani)
@@ -479,9 +497,10 @@ class SkaniPreclusterer(PreclusterBackend):
         logger.info("%d cross-shard pairs passed screening; "
                     "computing exact ANI ..", len(pairs))
         cache = PairDistanceCache()
-        anis = _guarded_ani_values(
-            [(profiles[i], profiles[j]) for i, j in pairs],
-            self.min_aligned_fraction, self.store.threads)
+        with timing.stage("fragment-ani"):
+            anis = _guarded_ani_values(
+                [(profiles[i], profiles[j]) for i, j in pairs],
+                self.min_aligned_fraction, self.store.threads)
         for (i, j), ani in zip(pairs, anis):
             if ani is not None and ani >= self.threshold:
                 cache.insert((i, j), ani)

@@ -292,7 +292,7 @@ _FLAG_DEFS: Tuple[Flag, ...] = (
          section="observability",
          help="Device-cost attribution for registered jit/Pallas "
               "entry points (XLA cost_analysis FLOPs/bytes, compile "
-              "walls, HBM high-water, roofline utilization) into the "
+              "and dispatch walls, HBM high-water) into the "
               "run report's device_costs section; 0 disables the "
               "profiled-dispatch path entirely"),
     Flag("GALAH_OBS_FLOW", kind="bool", default="1",

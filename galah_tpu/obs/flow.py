@@ -10,7 +10,10 @@ and **blocked** time with a reason (upstream-empty, downstream-full,
 device-dispatch, host, lock), and streams the pairings into bounded
 per-stage wait/service histograms plus Chrome-trace ``s``/``t``/``f``
 flow events (obs/trace.py) linking producer to consumer across the
-stage-token-adopting worker threads.
+stage-token-adopting worker threads. Service and blocked spans also
+hold a jax.profiler TraceAnnotation of their Chrome event's name
+(``<stage>:service``, ``<stage>:blocked[<reason>]``), so a profiler
+session shows them beside the device ops.
 
 On top of the recorded graph, :func:`critical_path` decomposes the
 end-to-end wall into per-stage **blame shares that sum to the wall**:
@@ -43,7 +46,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Deque, Dict, Iterator, List, Optional, Tuple
 
 #: Flow-item kinds, one per pipeline boundary object.
@@ -297,33 +300,40 @@ class FlowRecorder:
         """Measure a blocked region. ALWAYS measures (the returned
         object's ``.seconds`` feeds occupancy math even with flow
         disabled); records + traces only when enabled."""
+        from galah_tpu.obs import trace
+
         b = _Blocked()
+        name = f"{stage}:blocked[{reason}]"
         t0 = time.perf_counter()
         try:
-            yield b
+            with (trace.annotation(name) if self.enabled
+                  else nullcontext()):
+                yield b
         finally:
             b.seconds = time.perf_counter() - t0
             if self.enabled:
                 self.record_wait(stage, reason, b.seconds)
-                from galah_tpu.obs import trace
-                trace.emit_complete(f"{stage}:blocked[{reason}]", t0,
-                                    b.seconds, cat="flow")
+                trace.emit_complete(name, t0, b.seconds, cat="flow")
 
     @contextmanager
     def span(self, stage: Optional[str] = None,
              fid: Optional[int] = None) -> Iterator[None]:
         """A service span, bound into the thread-local flow context so
         nested record_* calls (and adopted workers) attribute here."""
+        from galah_tpu.obs import trace
+
+        traced = self.enabled and stage is not None
         t0 = time.perf_counter()
         self._tls.stack.append((stage, fid))
         try:
-            yield
+            with (trace.annotation(f"{stage}:service") if traced
+                  else nullcontext()):
+                yield
         finally:
             self._tls.stack.pop()
             dt = time.perf_counter() - t0
-            if self.enabled and stage is not None:
+            if traced:
                 self.record_service(stage, dt)
-                from galah_tpu.obs import trace
                 trace.emit_complete(f"{stage}:service", t0, dt,
                                     cat="flow")
                 if fid:

@@ -4,7 +4,7 @@ Wall-clock telemetry (utils/timing.py, obs/metrics.py) says how long a
 stage took; it cannot say whether the stage was compute-, memory-, or
 transfer-bound — the question every sketch-sizing and communication-
 avoidance decision needs answered (ROADMAP autotuning item). This
-module closes that gap with four measurements per registered entry
+module closes that gap with three measurements per registered entry
 point, all landing in the ``device_costs`` section of run_report.json
 (schema v3) and, through obs/ledger.py, in the cross-run perf ledger:
 
@@ -17,12 +17,11 @@ point, all landing in the ``device_costs`` section of run_report.json
   * HBM high-water — ``device.memory_stats()`` where the backend
     provides it (TPU), with a ``jax.live_arrays()`` fallback where it
     does not (CPU), sampled at compiles, periodically at calls, and at
-    stage boundaries (``sample_memory``);
-  * derived roofline utilization — achieved FLOP/s and bytes/s against
-    the published per-chip peaks (``PEAKS``). The peaks are bf16-MXU /
-    HBM datasheet numbers: integer-heavy sketch kernels will show low
-    MXU utilization by construction, so the ratio ranks stages against
-    each other, it is not an efficiency grade.
+    stage boundaries (``sample_memory``).
+
+No roofline is derived here: the dispatch wall is a host clock and the
+static costs are empty for Pallas kernels. A kernel's share of its
+roofline comes from the device trace (the benchmark's readers).
 
 Registration is the ``profiled(name)`` decorator stacked ABOVE
 ``jax.jit`` (the jit decorator stays visible to the GL2xx/GL3xx AST
@@ -55,7 +54,7 @@ import functools
 import sys
 import threading
 import time as _time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 # Concurrency contract, machine-checked by `galah-tpu lint` (GL8xx).
 # Per-entry counters live under the entry's own lock; the module-level
@@ -96,22 +95,6 @@ _ACTIVE = threading.local()
 
 _HOOK_INSTALLED = False
 _HOOK_LOCK = threading.Lock()
-
-#: Published per-chip peaks: device_kind prefix -> (FLOP/s, HBM B/s).
-#: bf16 MXU + HBM datasheet figures (module docstring caveat); "cpu"
-#: maps to None — no meaningful roofline for an unpinned host.
-PEAKS: Dict[str, Optional[Tuple[float, float]]] = {
-    "cpu": None,
-    "TPU v2": (46e12, 700e9),
-    "TPU v3": (123e12, 900e9),
-    "TPU v4": (275e12, 1228e9),
-    "TPU v5 lite": (197e12, 819e9),
-    "TPU v5p": (459e12, 2765e9),
-    "TPU v5": (459e12, 2765e9),
-    "TPU v6 lite": (918e12, 1640e9),
-    "TPU v6": (918e12, 1640e9),
-}
-
 
 def enabled() -> bool:
     """GALAH_OBS_PROFILE gate (default on; '0'/'false' disables)."""
@@ -370,17 +353,12 @@ class ProfiledFunction:
 
     # -- reporting ---------------------------------------------------
 
-    def snapshot(self, peak: Optional[Tuple[float, float]]) -> dict:
+    def snapshot(self) -> dict:
         with self._lock:
             memory: Dict[str, int] = {}
             for sig in self.signatures.values():
                 for k, v in sig.memory.items():
                     memory[k] = max(memory.get(k, 0), v)
-            wall = self.dispatch_wall_s
-            achieved_f = (self.flops_total / wall
-                          if wall > 0 and self.flops_total else None)
-            achieved_b = (self.bytes_total / wall
-                          if wall > 0 and self.bytes_total else None)
             return {
                 "calls": self.calls,
                 "plain_calls": self.plain_calls,
@@ -388,17 +366,10 @@ class ProfiledFunction:
                 "aot_fallbacks": self.aot_fallbacks,
                 "flops": self.flops_total or None,
                 "bytes_accessed": self.bytes_total or None,
-                "dispatch_wall_s": wall,
+                "dispatch_wall_s": self.dispatch_wall_s,
                 "compile_wall_s": self.compile_wall_s,
                 "monitored_compile_s": self.monitored_compile_s,
                 "memory": memory,
-                "achieved_flops_per_s": achieved_f,
-                "achieved_bytes_per_s": achieved_b,
-                "flops_utilization": (achieved_f / peak[0]
-                                      if peak and achieved_f else None),
-                "bandwidth_utilization": (achieved_b / peak[1]
-                                          if peak and achieved_b
-                                          else None),
             }
 
 
@@ -467,41 +438,15 @@ def sample_memory(stage: Optional[str] = None) -> Optional[int]:
 
 
 # ---------------------------------------------------------------------------
-# Roofline peaks + snapshot
+# Snapshot
 # ---------------------------------------------------------------------------
-
-
-def device_peaks() -> dict:
-    """The roofline peak entry for the current backend: device kind
-    plus (peak FLOP/s, peak bytes/s), nulls when unknown/CPU."""
-    out = {"device_kind": None, "peak_flops_per_s": None,
-           "peak_bytes_per_s": None}
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return out
-    try:
-        kind = str(jax.devices()[0].device_kind)
-    except Exception:
-        return out
-    out["device_kind"] = kind
-    best = None
-    for prefix, peak in PEAKS.items():
-        if kind.lower().startswith(prefix.lower()) and peak is not None:
-            if best is None or len(prefix) > best[0]:
-                best = (len(prefix), peak)
-    if best is not None:
-        out["peak_flops_per_s"], out["peak_bytes_per_s"] = best[1]
-    return out
 
 
 def snapshot() -> dict:
     """The ``device_costs`` section of run_report.json (schema v3)."""
-    peaks = device_peaks()
-    peak = (None if peaks["peak_flops_per_s"] is None
-            else (peaks["peak_flops_per_s"], peaks["peak_bytes_per_s"]))
     with _REGISTRY_LOCK:
         registry = list(_REGISTRY)
-    entries = {pf.name: pf.snapshot(peak) for pf in registry
+    entries = {pf.name: pf.snapshot() for pf in registry
                if pf.calls or pf.signatures}
     with _LOCK:
         hbm = {"peak_bytes": _HBM["peak_bytes"],
@@ -512,7 +457,6 @@ def snapshot() -> dict:
         "profiling_enabled": enabled(),
         "entries": entries,
         "hbm": hbm,
-        "peaks": peaks,
         "compile": totals,
     }
 

@@ -469,14 +469,8 @@ def render(report: dict) -> str:
             lines.append(f"    ... {len(events) - 20} more")
     dc = report.get("device_costs")
     if dc and dc.get("entries"):
-        peaks = dc.get("peaks", {})
         hbm = dc.get("hbm", {})
         lines += ["", "device costs (profiled entry points):"]
-        if peaks.get("device_kind"):
-            pk = peaks.get("peak_flops_per_s")
-            lines.append(
-                f"  device kind: {peaks['device_kind']}"
-                + (f" (peak {pk:.3g} FLOP/s)" if pk else ""))
         if hbm.get("peak_bytes") is not None:
             lines.append(
                 f"  HBM high-water: {hbm['peak_bytes'] / 2**20:.1f} "
@@ -484,7 +478,6 @@ def render(report: dict) -> str:
         for name, e in sorted(dc["entries"].items()):
             flops = e.get("flops")
             byts = e.get("bytes_accessed")
-            util = e.get("flops_utilization")
             parts = [f"calls={e.get('calls', 0)}",
                      f"compile={_fmt_s(e.get('compile_wall_s', 0.0))}",
                      f"dispatch={_fmt_s(e.get('dispatch_wall_s', 0.0))}"]
@@ -492,8 +485,6 @@ def render(report: dict) -> str:
                 parts.append(f"flops={flops:.3g}")
             if byts:
                 parts.append(f"bytes={byts:.3g}")
-            if util is not None:
-                parts.append(f"mxu={100.0 * util:.2f}%")
             lines.append(f"  {name}: " + " ".join(parts))
     san = report.get("sanitizer")
     if san is not None:

@@ -25,16 +25,25 @@ What lands in the trace:
 This is complementary to --profile-trace-dir (the XLA profiler): that
 captures device timelines below the dispatch boundary; this captures
 the host-side pipeline structure above it. Both load in Perfetto.
+
+The same spans also reach the XLA profiler: `annotation(name)` opens a
+``jax.profiler.TraceAnnotation`` around every StageTimer stage and
+flow span, so inside any profiler session (`--profile-trace-dir`, or a
+harness's own `jax.profiler.start_trace`) they land on the xplane's
+``/host:CPU`` plane on the device ops' clock. Outside a session a
+TraceMe costs one inactive-recorder check.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
+import sys
 import threading
 import time
-from typing import Optional
+from typing import ContextManager, Optional
 
 logger = logging.getLogger(__name__)
 
@@ -179,6 +188,18 @@ def emit_flow(ph: str, name: str, flow_id: int,
     rec = RECORDER
     if rec is not None:
         rec.flow(ph, name, flow_id, cat=cat)
+
+
+def annotation(name: str,
+               args: Optional[dict] = None) -> ContextManager:
+    """A ``jax.profiler.TraceAnnotation`` named `name` (with `args` as
+    its trace arguments), so the span shows in any profiler session on
+    the device's clock; a no-op where jax was never imported (obs stays
+    importable without it)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    return profiler.TraceAnnotation(name, **(args or {}))
 
 
 def _install_jax_hooks() -> None:

@@ -817,15 +817,17 @@ def _warm_sorted_queries(queries: "list[Tuple[GenomeProfile, GenomeProfile]]",
     """Build each distinct query profile's sorted_query cache, on up to
     `threads` pool workers: its argsort (~0.2-0.3 s for a 3 Mbp genome)
     releases the GIL, and a catalogue's exact-ANI pass needs one per
-    genome before its first pair."""
+    genome before its first pair. Span: `fragment-ani.sort-queries`."""
     todo = [q for q in {id(q): q for q, _ in queries}.values()
             if q._np_sorted_query is None]
-    if threads > 1 and len(todo) > 1:
-        from galah_tpu.io.prefetch import _shared_pool
+    with timing.stage("fragment-ani.sort-queries"):
+        if threads > 1 and len(todo) > 1:
+            from galah_tpu.io.prefetch import _shared_pool
 
-        list(_shared_pool(threads).map(GenomeProfile.sorted_query, todo))
-    for q in todo:
-        q.sorted_query()
+            list(_shared_pool(threads).map(GenomeProfile.sorted_query,
+                                           todo))
+        for q in todo:
+            q.sorted_query()
 
 
 def _resolve_fragment_strategy(
@@ -885,6 +887,7 @@ def directed_ani_batch(
         return []
     strategy, _explicit = _resolve_fragment_strategy()
     timing.counter(f"fragment-strategy-{strategy}", 1)
+    timing.counter("fragment-ani-directions", len(queries))
     if strategy == "c":
         return _directed_ani_batch_cmerge(
             queries, identity_floor, min_window_valid_frac, threads)
@@ -1075,7 +1078,13 @@ def _directed_ani_batch_pallas(
         for j, flags in pallas_fragment.iter_element_hits(
                 items, interpret=interpret):
             _qh, qw, totals = queries[idxs[j]][0].sorted_query()
-            matched_by_item[j] = _fold_flags(flags, qw, totals.shape[0])
+            if not flags.shape[0]:
+                # an empty query matches in no window
+                matched_by_item[j] = np.zeros(totals.shape[0], np.int32)
+                continue
+            with timing.stage("fragment-ani.fold", {"direction": j}):
+                matched_by_item[j] = _fold_flags(flags, qw,
+                                                 totals.shape[0])
 
         matched_parts, total_parts, starts, live = [], [], [], []
         seg = 0
@@ -1095,11 +1104,12 @@ def _directed_ani_batch_pallas(
             live.append(n)
         if not live:
             continue
-        ani, af, fm, ft = _directed_from_counts_arrays(
-            np.concatenate(matched_parts),
-            np.concatenate(total_parts),
-            np.asarray(starts, dtype=np.int64), k, fraglen,
-            subsample_c, identity_floor, min_window_valid_frac)
+        with timing.stage("fragment-ani.reduce"):
+            ani, af, fm, ft = _directed_from_counts_arrays(
+                np.concatenate(matched_parts),
+                np.concatenate(total_parts),
+                np.asarray(starts, dtype=np.int64), k, fraglen,
+                subsample_c, identity_floor, min_window_valid_frac)
         for i, n in enumerate(live):
             out[n] = DirectedANI(float(ani[i]), float(af[i]),
                                  int(fm[i]), int(ft[i]))
@@ -1227,6 +1237,7 @@ def bidirectional_ani_values(
             threads=threads)]
 
     directed = [(a, b) for a, b in pairs] + [(b, a) for a, b in pairs]
+    timing.counter("fragment-ani-directions", len(directed))
     ani, _af, fm, ft = _directed_ani_arrays_c(
         directed, identity_floor, DEFAULT_MIN_WINDOW_VALID_FRAC,
         threads)

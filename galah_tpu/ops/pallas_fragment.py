@@ -333,7 +333,6 @@ def _dispatch(qh: np.ndarray, ref: np.ndarray,
     span_max = _pow2(int(blocks.max()))
 
     timing.counter("fragment-pallas-launches", 1)
-    timing.counter("fragment-pallas-pairs", 1)
     timing.counter("fragment-pallas-jobs", n_jobs)
     timing.counter("fragment-pallas-job-slots", n_slots)
     timing.counter("fragment-pallas-ref-blocks", n_slots * span_max)
@@ -357,14 +356,20 @@ def iter_element_hits(
     same array objects across calls reuse their device planes. `flags`
     is int8 0/1 aligned to `qh` — `flags[e] == 1` iff `qh[e]` is a
     member of `ref`.
+
+    Each live item's launch (plane lookup or upload, plan, launch) and
+    its blocking flag fetch are the spans `fragment-ani.launch` and
+    `fragment-ani.fetch`, both with the item's index as `direction`.
     """
     pending: "collections.deque" = collections.deque()
 
     def fetch():
         i, n_q, dev = pending.popleft()
-        flags = np.asarray(dev).reshape(-1)[:n_q]
-        if n_q:
-            timing.dispatch(sync=True)
+        if not n_q:
+            return i, dev
+        with timing.stage("fragment-ani.fetch", {"direction": i}):
+            flags = np.asarray(dev).reshape(-1)[:n_q]
+        timing.dispatch(sync=True)
         return i, flags
 
     for i, (qh, ref) in enumerate(items):
@@ -372,8 +377,9 @@ def iter_element_hits(
             # empty queries hit nothing by definition
             pending.append((i, 0, np.zeros(0, dtype=np.int8)))
         else:
-            pending.append((i, qh.shape[0],
-                            _dispatch(qh, ref, interpret)))
+            with timing.stage("fragment-ani.launch", {"direction": i}):
+                dev = _dispatch(qh, ref, interpret)
+            pending.append((i, qh.shape[0], dev))
         if len(pending) > PIPELINE_DEPTH:
             yield fetch()
     while pending:

@@ -11,7 +11,9 @@ The StageTimer is the emission surface of the telemetry layer
 (galah_tpu/obs/): every closed span also records into the stage
 wall-clock TREE the run report serializes (obs/report.py) and, when a
 trace recorder is active (--trace-events), lands as a Chrome-trace
-span on the Perfetto timeline (obs/trace.py).
+span on the Perfetto timeline (obs/trace.py). While open, a stage also
+holds a jax.profiler TraceAnnotation of its name, so any profiler
+session shows it on the device ops' clock.
 
 Worker-thread attribution: the active-stage stack is thread-local, but
 dispatches can arrive from worker threads (IO prefetch pools, per-
@@ -138,14 +140,18 @@ class StageTimer:
         return dict(self._counters)
 
     @contextlib.contextmanager
-    def stage(self, name: str) -> Iterator[None]:
+    def stage(self, name: str,
+              args: Optional[dict] = None) -> Iterator[None]:
+        """A named span. `args` (e.g. a pair's index) go on its trace
+        events only, not into the tree, which sums spans by name."""
         start = time.perf_counter()
         st = self._stack()
         st.append(name)
         with self._lock:
             self._shared.append(name)
         try:
-            yield
+            with _obs_trace.annotation(name, args):
+                yield
         finally:
             st.pop()
             path = tuple(st) + (name,)
@@ -169,7 +175,8 @@ class StageTimer:
                     self._tree_order.append(path)
                 self._tree[path][0] += dt
                 self._tree[path][1] += 1
-            _obs_trace.emit_complete(name, start, dt, cat="stage")
+            _obs_trace.emit_complete(name, start, dt, cat="stage",
+                                     args=args)
             logger.debug("stage %s: %.3fs", name, dt)
 
     def items(self) -> List[Tuple[str, float, int]]:
@@ -231,8 +238,8 @@ class StageTimer:
 GLOBAL = StageTimer()
 
 
-def stage(name: str):
-    return GLOBAL.stage(name)
+def stage(name: str, args: Optional[dict] = None):
+    return GLOBAL.stage(name, args)
 
 
 def counter(name: str, delta: int) -> None:

@@ -42,9 +42,8 @@ _T0 = time.monotonic()
 
 # Launch-related counters copied into each pallas row (deltas across
 # the timed call), mirroring the pairlist stage's waste counters.
-_COUNTERS = ("fragment-pallas-launches", "fragment-pallas-pairs",
-             "fragment-pallas-jobs", "fragment-pallas-job-slots",
-             "fragment-pallas-ref-blocks",
+_COUNTERS = ("fragment-pallas-launches", "fragment-pallas-jobs",
+             "fragment-pallas-job-slots", "fragment-pallas-ref-blocks",
              "fragment-pallas-ref-blocks-needed",
              "fragment-pallas-ref-rows", "fragment-pallas-uploads")
 

@@ -449,8 +449,7 @@ def test_report_diff_v2_v3_is_additive_compatible(tmp_path, capsys):
     v3.setdefault("device_costs", {"profiling_enabled": True,
                                    "entries": {}, "hbm": {
                                        "peak_bytes": 0, "source": None,
-                                       "per_stage": {}},
-                                   "peaks": None})
+                                       "per_stage": {}}})
     v2 = json.loads(json.dumps(v3))
     del v2["device_costs"]
     v2["version"] = 2

@@ -44,6 +44,10 @@ def _element_hits(items):
             for _i, flags in pf.iter_element_hits(items, interpret=True)]
 
 
+def _span_counts():
+    return {name: count for name, _s, count in timing.GLOBAL.items()}
+
+
 def _counter_delta(before, after):
     return {k: after.get(k, 0) - before.get(k, 0)
             for k in set(before) | set(after)
@@ -91,8 +95,10 @@ def test_kernel_hits_match_numpy_membership_lattice():
     items.append((np.sort(u64s(900)), shared))
 
     before = timing.GLOBAL.counters()
+    spans_before = _span_counts()
     hits = _element_hits(items)
     delta = _counter_delta(before, timing.GLOBAL.counters())
+    spans = _counter_delta(spans_before, _span_counts())
 
     for (qh, ref), h in zip(items, hits):
         expect = np.isin(qh, ref).astype(np.int32)
@@ -101,7 +107,10 @@ def test_kernel_hits_match_numpy_membership_lattice():
     # without one, and the shared reference is uploaded once
     live = [it for it in items if it[0].shape[0]]
     assert delta.get("fragment-pallas-launches") == len(live)
-    assert delta.get("fragment-pallas-pairs") == len(live)
+    # directions are counted a layer up (fragment_ani); here each live
+    # item's launch and fetch close one span each
+    assert spans.get("fragment-ani.launch") == len(live)
+    assert spans.get("fragment-ani.fetch") == len(live)
     refs = {id(ref) for _q, ref in live}
     assert len(refs) == len(live) - 1
     assert delta.get("fragment-pallas-uploads") == len(live) + len(refs)
@@ -164,7 +173,10 @@ def strategy_results(pairs):
     evidence) — computed once for the whole module."""
     pf.clear_plane_cache()
     before = timing.GLOBAL.counters()
-    res = {"pallas": fa._directed_ani_batch_pallas(pairs, FLOOR, FRAC)}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("GALAH_TPU_FRAGMENT_STRATEGY", "pallas")
+        res = {"pallas": fa.directed_ani_batch(
+            pairs, FLOOR, FRAC, threads=1)}
     counters = _counter_delta(before, timing.GLOBAL.counters())
     res["xla"] = fa._directed_ani_batch_xla(pairs, FLOOR, FRAC)
     if fa._c_merge_available():
@@ -229,7 +241,7 @@ def test_one_launch_per_pair_planes_uploaded_once(profiles, pairs,
     them."""
     _, counters = strategy_results
     assert counters["fragment-pallas-launches"] == len(pairs)
-    assert counters["fragment-pallas-pairs"] == len(pairs)
+    assert counters["fragment-ani-directions"] == len(pairs)
     assert counters["fragment-pallas-uploads"] == 2 * len(profiles)
     assert counters["fragment-pallas-jobs"] <= \
         counters["fragment-pallas-job-slots"]

@@ -215,7 +215,6 @@ def _report(duration=12.0, n=256, extra_metrics=None):
                 "dispatch_wall_s": 1.25, "compile_wall_s": 0.5}},
             "hbm": {"peak_bytes": 1 << 20, "source": "live_arrays",
                     "per_stage": {}},
-            "peaks": None,
         },
     }
     if extra_metrics:
