@@ -23,6 +23,7 @@
 #include <pthread.h>
 #include <stdint.h>
 #include <stdlib.h>
+#include <string.h>
 
 #if defined(__x86_64__) && defined(__GNUC__)
 #include <immintrin.h>
@@ -555,4 +556,220 @@ void galah_fill_windows_pairs(const int64_t *pos, const uint64_t *h,
         if (c < slots) wins[w * slots + c] = h[i];
     }
     (void)W;
+}
+
+/* Sorted exact-ANI query of one profile — the C twin of
+ * fragment_ani.sorted_query_numpy (windows() -> mask -> nonzero ->
+ * stable argsort -> gathers). Row w of the window matrix
+ * is flat[w*L, w*L + L - (k - 1)) clipped to n_flat (the tail k-mers
+ * cross the fragment boundary); SENTINEL entries do not survive.
+ *
+ * galah_sorted_query_totals: per-window survivor counts and the OR of
+ * every surviving hash (its highest set bit places the first radix
+ * digit, so a FracMinHash-subsampled profile, whose hashes all sit
+ * below 2^64/c, still spreads over every bucket). Returns the
+ * survivor count nq, which sizes qh/qw.
+ *
+ * galah_sorted_query: the survivors as (hash, window) pairs sorted by
+ * hash, equal hashes in ascending window order — the stable argsort's
+ * output. Most-significant-digit radix sort: the window walk scatters
+ * into qh/qw by the top digit (stable, window order kept); each bucket
+ * goes through a scratch the size of the largest bucket and back into
+ * place by the next digit, sized to the bucket so a sub-bucket holds a
+ * pair or two; a stable insertion (merge, past SQ_SMALL pairs — a
+ * repeat's run of equal hashes) sort finishes each sub-bucket. The
+ * hashes are uniform (murmur3), so the digits split evenly and the
+ * scratch stays cache-sized. Returns 0; -1 if the scratch could not be
+ * allocated; -2, with qh/qw untouched, if nq is not the survivor
+ * count. */
+
+#define SQ_DIGIT_BITS 11
+#define SQ_SMALL 32
+
+int64_t galah_sorted_query_totals(const uint64_t *flat, int64_t n_flat,
+                                  int64_t W, int64_t L, int k,
+                                  int32_t *totals, uint64_t *hash_or) {
+    const uint64_t SENT = 0xFFFFFFFFFFFFFFFFull;
+    const int64_t keep = L - (k - 1);
+    int64_t nq = 0;
+    uint64_t acc = 0;
+    for (int64_t w = 0; w < W; w++) {
+        int64_t s = w * L, e = s + keep;
+        if (e > n_flat) e = n_flat;
+        int32_t c = 0;
+        for (int64_t i = s; i < e; i++) {
+            uint64_t h = flat[i];
+            if (h == SENT) continue;
+            c++;
+            acc |= h;
+        }
+        totals[w] = c;
+        nq += c;
+    }
+    *hash_or = acc;
+    return nq;
+}
+
+static void sq_isort(uint64_t *h, int32_t *w, int64_t n) {
+    for (int64_t i = 1; i < n; i++) {
+        uint64_t kh = h[i];
+        int32_t kw = w[i];
+        int64_t j = i;
+        while (j > 0 && h[j - 1] > kh) {
+            h[j] = h[j - 1];
+            w[j] = w[j - 1];
+            j--;
+        }
+        h[j] = kh;
+        w[j] = kw;
+    }
+}
+
+/* stable merge sort of h/w[0, n) in place, sh/sw[0, n) as scratch */
+static void sq_msort(uint64_t *h, int32_t *w, uint64_t *sh, int32_t *sw,
+                     int64_t n) {
+    if (n <= SQ_SMALL) {
+        sq_isort(h, w, n);
+        return;
+    }
+    int64_t mid = n / 2;
+    sq_msort(h, w, sh, sw, mid);
+    sq_msort(h + mid, w + mid, sh + mid, sw + mid, n - mid);
+    if (h[mid - 1] <= h[mid]) return;
+    memcpy(sh, h, (size_t)mid * sizeof(uint64_t));
+    memcpy(sw, w, (size_t)mid * sizeof(int32_t));
+    /* the output cursor o = i + (j - mid) never passes j */
+    int64_t i = 0, j = mid, o = 0;
+    while (i < mid && j < n) {
+        if (h[j] < sh[i]) {
+            h[o] = h[j];
+            w[o++] = w[j++];
+        } else {
+            h[o] = sh[i];
+            w[o++] = sw[i++];
+        }
+    }
+    while (i < mid) {
+        h[o] = sh[i];
+        w[o++] = sw[i++];
+    }
+}
+
+static int sq_floor_log2(int64_t n) {
+    return 63 - __builtin_clzll((uint64_t)n);
+}
+
+int galah_sorted_query(const uint64_t *flat, int64_t n_flat, int64_t W,
+                       int64_t L, int k, uint64_t hash_or, int64_t nq,
+                       uint64_t *qh, int32_t *qw) {
+    const uint64_t SENT = 0xFFFFFFFFFFFFFFFFull;
+    if (nq <= 0) return 0;
+    const int64_t keep = L - (k - 1);
+    int hb = hash_or ? 64 - __builtin_clzll(hash_or) : 0;
+    int b1 = hb < SQ_DIGIT_BITS ? hb : SQ_DIGIT_BITS;
+    int shift1 = hb - b1;
+    int64_t nb1 = (int64_t)1 << b1;
+    int64_t *off = (int64_t *)calloc((size_t)nb1 + 1, sizeof(int64_t));
+    int64_t *sub = (int64_t *)malloc(
+        (((size_t)1 << SQ_DIGIT_BITS) + 1) * sizeof(int64_t));
+    if (!off || !sub) {
+        free(off);
+        free(sub);
+        return -1;
+    }
+
+    /* top digit: histogram, then a stable scatter in window order
+     * straight into qh/qw */
+    for (int64_t w = 0; w < W; w++) {
+        int64_t s = w * L, e = s + keep;
+        if (e > n_flat) e = n_flat;
+        for (int64_t i = s; i < e; i++)
+            if (flat[i] != SENT) off[(flat[i] >> shift1) + 1]++;
+    }
+    int64_t big = 0;
+    for (int64_t b = 0; b < nb1; b++) {
+        if (off[b + 1] > big) big = off[b + 1];
+        off[b + 1] += off[b];
+    }
+    if (off[nb1] != nq) {
+        /* not the count galah_sorted_query_totals gave: qh/qw are
+         * sized for nq */
+        free(off);
+        free(sub);
+        return -2;
+    }
+    for (int64_t w = 0; w < W; w++) {
+        int64_t s = w * L, e = s + keep;
+        if (e > n_flat) e = n_flat;
+        for (int64_t i = s; i < e; i++) {
+            uint64_t h = flat[i];
+            if (h == SENT) continue;
+            int64_t p = off[h >> shift1]++;
+            qh[p] = h;
+            qw[p] = (int32_t)w;
+        }
+    }
+    if (shift1 == 0) {
+        /* every hash of a bucket is equal: stable as it is */
+        free(off);
+        free(sub);
+        return 0;
+    }
+
+    /* off[b] is now bucket b's end. Each bucket is copied to a
+     * cache-sized scratch and scattered back by its next digit; the
+     * scratch is then free for the sub-buckets' merge sorts. */
+    uint64_t *th = (uint64_t *)malloc((size_t)big * sizeof(uint64_t));
+    int32_t *tw = (int32_t *)malloc((size_t)big * sizeof(int32_t));
+    if (!th || !tw) {
+        free(th);
+        free(tw);
+        free(off);
+        free(sub);
+        return -1;
+    }
+    int64_t start = 0;
+    for (int64_t b = 0; b < nb1; b++) {
+        int64_t end = off[b], m = end - start;
+        uint64_t *bh = qh + start;
+        int32_t *bw = qw + start;
+        if (m <= SQ_SMALL) {
+            sq_isort(bh, bw, m);
+            start = end;
+            continue;
+        }
+        int b2 = sq_floor_log2(m);
+        if (b2 > SQ_DIGIT_BITS) b2 = SQ_DIGIT_BITS;
+        if (b2 > shift1) b2 = shift1;
+        int shift2 = shift1 - b2;
+        int64_t nb2 = (int64_t)1 << b2;
+        uint64_t mask2 = (uint64_t)nb2 - 1;
+        memcpy(th, bh, (size_t)m * sizeof(uint64_t));
+        memcpy(tw, bw, (size_t)m * sizeof(int32_t));
+        memset(sub, 0, (size_t)(nb2 + 1) * sizeof(int64_t));
+        for (int64_t i = 0; i < m; i++)
+            sub[((th[i] >> shift2) & mask2) + 1]++;
+        for (int64_t d = 0; d < nb2; d++) sub[d + 1] += sub[d];
+        for (int64_t i = 0; i < m; i++) {
+            int64_t p = sub[(th[i] >> shift2) & mask2]++;
+            bh[p] = th[i];
+            bw[p] = tw[i];
+        }
+        /* sub[d] is now sub-bucket d's end */
+        if (shift2) {
+            int64_t s2 = 0;
+            for (int64_t d = 0; d < nb2; d++) {
+                int64_t e2 = sub[d];
+                if (e2 - s2 > 1)
+                    sq_msort(bh + s2, bw + s2, th + s2, tw + s2, e2 - s2);
+                s2 = e2;
+            }
+        }
+        start = end;
+    }
+    free(th);
+    free(tw);
+    free(off);
+    free(sub);
+    return 0;
 }

@@ -214,6 +214,53 @@ def compact_windows(flat: np.ndarray, n_windows: int, fraglen: int,
     return wins
 
 
+_fn_sqt = _lib.galah_sorted_query_totals
+_fn_sqt.restype = ctypes.c_int64
+_fn_sqt.argtypes = [
+    ctypes.POINTER(ctypes.c_uint64), ctypes.c_int64, ctypes.c_int64,
+    ctypes.c_int64, ctypes.c_int, ctypes.POINTER(ctypes.c_int32),
+    ctypes.POINTER(ctypes.c_uint64),
+]
+
+_fn_sq = _lib.galah_sorted_query
+_fn_sq.restype = ctypes.c_int
+_fn_sq.argtypes = [
+    ctypes.POINTER(ctypes.c_uint64), ctypes.c_int64, ctypes.c_int64,
+    ctypes.c_int64, ctypes.c_int, ctypes.c_uint64, ctypes.c_int64,
+    ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_int32),
+]
+
+
+def sorted_query(flat: np.ndarray, n_windows: int, fraglen: int,
+                 k: int) -> "tuple[np.ndarray, np.ndarray, np.ndarray]":
+    """(qh, qw, totals) of a flat SENTINEL-masked positional-hash array
+    — C twin of fragment_ani.sorted_query_numpy over the profile's
+    windows() (mask, nonzero, stable argsort, gathers), bit-identical:
+    the surviving window hashes sorted ascending, equal hashes in
+    ascending window order, their window ids, and each window's
+    survivor count. One walk for the counts and two for a radix sort,
+    with no (W, L) matrix."""
+    flat = np.ascontiguousarray(flat, dtype=np.uint64)
+    totals = np.empty(n_windows, dtype=np.int32)
+    hash_or = ctypes.c_uint64(0)
+    flat_p = flat.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64))
+    nq = _fn_sqt(flat_p, flat.shape[0], n_windows, fraglen, int(k),
+                 totals.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+                 ctypes.byref(hash_or))
+    qh = np.empty(nq, dtype=np.uint64)
+    qw = np.empty(nq, dtype=np.int32)
+    rc = _fn_sq(flat_p, flat.shape[0], n_windows, fraglen, int(k),
+                hash_or.value, nq,
+                qh.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
+                qw.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
+    if rc == -1:
+        raise MemoryError(f"sorted query scratch for {nq} hashes")
+    if rc != 0:
+        raise RuntimeError(f"sorted query walk disagrees with its "
+                           f"survivor count {nq}")
+    return qh, qw, totals
+
+
 _fn_wcp = _lib.galah_window_counts_pairs
 _fn_wcp.restype = None
 _fn_wcp.argtypes = [

@@ -92,10 +92,11 @@ class GenomeProfile:
     # single-device upload and the batch-sharding assembly path)
     _np_windows_padded: Optional[np.ndarray] = None
     _np_ref_padded: Optional[np.ndarray] = None
-    # unpadded windows, cached for the C membership fast path
+    # unpadded windows, cached for padded_windows() and the numpy
+    # sorted_query twin
     _np_windows: Optional[np.ndarray] = None
     # (sorted hashes, their window ids, per-window totals) — cached for
-    # the C merge membership fast path; totals are pair-independent
+    # the C merge and Pallas strategies; totals are pair-independent
     _np_sorted_query: "Optional[tuple]" = None
     # kept (hash, position) pairs from the C profile walk: lets
     # windows() assemble compacted rows in O(n_valid) instead of two
@@ -110,18 +111,28 @@ class GenomeProfile:
     def sorted_query(self) -> "tuple[np.ndarray, np.ndarray, np.ndarray]":
         """(qh, qw, totals): the profile's surviving window hashes
         sorted ascending, their window row ids, and each window's
-        valid-hash count. Built once from windows() and cached — the
-        merge membership path (csrc/pairstats.c::
-        galah_window_match_counts_merge) consumes it per pair."""
+        valid-hash count; equal hashes in ascending window order.
+        Built once and cached — the exact-ANI strategies that read it
+        (the C merge, csrc/pairstats.c::galah_window_match_counts_merge,
+        and the Pallas kernel) consume it per pair.
+
+        The C walk over flat_hashes (csrc/pairstats.c::
+        galah_sorted_query) builds it without the windows() matrix;
+        sorted_query_numpy over windows() is its bit-identical twin
+        where the toolchain is absent. flat_hashes holds exactly the surviving k-mers of
+        windows() at every subsample_c: the FracMinHash mask is
+        positional, and the profile walk's kept (pos, hash) pairs are
+        the flat array's non-SENTINEL entries."""
         if self._np_sorted_query is None:
-            wins = self.windows()
-            mask = wins != np.uint64(SENTINEL)
-            totals = mask.sum(axis=1, dtype=np.int32)
-            rows, _cols = np.nonzero(mask)
-            qh = wins[mask]
-            order = np.argsort(qh)
-            self._np_sorted_query = (
-                qh[order], rows[order].astype(np.int32), totals)
+            try:
+                from galah_tpu.ops import _cpairstats
+            except ImportError:
+                self._np_sorted_query = sorted_query_numpy(self.windows())
+            else:
+                self._np_sorted_query = _cpairstats.sorted_query(
+                    self.flat_hashes, self.n_windows, self.fraglen,
+                    self.k)
+                timing.counter("fragment-sorted-query-native", 1)
         return self._np_sorted_query
 
     def padded_windows(self) -> np.ndarray:
@@ -203,6 +214,21 @@ class GenomeProfile:
             wins = wins[:, :slots].copy()
         self._np_windows = wins
         return wins
+
+
+def sorted_query_numpy(
+        wins: np.ndarray) -> "tuple[np.ndarray, np.ndarray, np.ndarray]":
+    """GenomeProfile.sorted_query's (qh, qw, totals) from its windows()
+    matrix — the numpy twin of csrc/pairstats.c::galah_sorted_query.
+    The stable argsort keeps np.nonzero's row-major order among equal
+    hashes, so they come out in ascending window order, as the C sort
+    emits them."""
+    mask = wins != np.uint64(SENTINEL)
+    totals = mask.sum(axis=1, dtype=np.int32)
+    rows, _cols = np.nonzero(mask)
+    qh = wins[mask]
+    order = np.argsort(qh, kind="stable")
+    return qh[order], rows[order].astype(np.int32), totals
 
 
 # Half the generic hashing.BATCH_BUDGET: profile batches download the
@@ -815,7 +841,8 @@ def _c_merge_available() -> bool:
 def _warm_sorted_queries(queries: "list[Tuple[GenomeProfile, GenomeProfile]]",
                          threads: int) -> None:
     """Build each distinct query profile's sorted_query cache, on up to
-    `threads` pool workers: its argsort (~0.2-0.3 s for a 3 Mbp genome)
+    `threads` pool workers: the C build (~0.1 s for a 3 Mbp genome on
+    one x86 core; ~0.8-1.0 s for the numpy twin's stable argsort)
     releases the GIL, and a catalogue's exact-ANI pass needs one per
     genome before its first pair. Span: `fragment-ani.sort-queries`."""
     todo = [q for q in {id(q): q for q, _ in queries}.values()

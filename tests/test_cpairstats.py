@@ -484,3 +484,119 @@ def test_merge_counter_avx512_scalar_identity(monkeypatch):
         qh_cat, qw_cat, q_off, ref_cat, r_off, pair_q, pair_r,
         m_off, n_pairs * nw, threads=2)
     np.testing.assert_array_equal(got, want)
+
+
+# -- sorted exact-ANI query: C build vs the numpy twin -----------------
+
+_SQ_K, _SQ_FRAGLEN = 21, 1000
+
+
+def _sq_codes(case):
+    """(codes, contig_offsets) of a genome shaped for one parity case."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+
+    def acgt(n):
+        return rng.integers(0, 4, size=n).astype(np.uint8)
+
+    if case == "empty":
+        parts = [np.zeros(0, dtype=np.uint8)]
+    elif case == "shorter_than_k":
+        parts = [acgt(_SQ_K - 1)]
+    elif case == "one_partial_window":
+        parts = [acgt(700)]
+    elif case == "last_partial_window":
+        # two contigs: k-mers spanning the contig boundary are masked
+        parts = [acgt(3000), acgt(2300)]
+    elif case == "sentinel_windows":
+        # windows 1-3 hold only ambiguous bases: every slot SENTINEL
+        parts = [np.concatenate([acgt(1000),
+                                 np.full(3000, 255, dtype=np.uint8),
+                                 acgt(1500)])]
+    elif case == "heavy_repeats":
+        # equal hashes across many windows: a tandem repeat, a
+        # homopolymer run (one hash tens of thousands of times) and a
+        # segment copied twice
+        seg = acgt(20_000)
+        parts = [np.concatenate([
+            acgt(60_000), np.tile(acgt(37), 1500),
+            np.zeros(30_000, dtype=np.uint8), seg, acgt(5_000), seg,
+            acgt(7_777)])]
+    else:
+        raise ValueError(case)
+    codes = np.concatenate(parts)
+    offsets = np.concatenate(
+        [[0], np.cumsum([p.shape[0] for p in parts])]).astype(np.int64)
+    return codes, offsets
+
+
+def _sq_profile(case, subsample_c, build):
+    from galah_tpu.io.fasta import Genome, GenomeStats
+    from galah_tpu.ops import fragment_ani as fa
+
+    if build == "direct":
+        # hashes sharing their top bits land in one radix bucket and
+        # sub-bucket, so the stable merge sort orders distinct hashes
+        # and ties there; all sit below 2^64/125 and survive the mask
+        rng = np.random.default_rng(5)
+        flat = (np.uint64(1 << 56)
+                + rng.integers(0, 500, size=50_000).astype(np.uint64))
+        flat[rng.random(flat.shape[0]) < 0.1] = np.uint64(SENTINEL)
+        return fa.GenomeProfile(
+            path=case, k=_SQ_K, fraglen=_SQ_FRAGLEN, flat_hashes=flat,
+            ref_set=np.unique(flat[flat != np.uint64(SENTINEL)]),
+            markers=np.zeros(0, dtype=np.uint64),
+            subsample_c=subsample_c)
+    codes, offsets = _sq_codes(case)
+    g = Genome(path=f"{case}.fna", codes=codes, contig_offsets=offsets,
+               stats=GenomeStats(len(offsets) - 1,
+                                 int((codes == 255).sum()), codes.shape[0]))
+    if build == "flat":
+        return fa._profile_from_flat(
+            g.path, fa.positional_hashes(g, _SQ_K), _SQ_K, _SQ_FRAGLEN,
+            subsample_c)
+    prof = fa._profile_via_c(g, _SQ_K, _SQ_FRAGLEN, subsample_c)
+    # the C walk keeps (pos, hash) pairs for subsampled profiles, and
+    # windows() then assembles its rows from them
+    assert (prof._kept_pos is not None) == (subsample_c > 1)
+    return prof
+
+
+_SQ_CASES = [
+    (case, c, build)
+    for case in ("empty", "shorter_than_k", "one_partial_window",
+                 "last_partial_window", "sentinel_windows",
+                 "heavy_repeats")
+    for c in (1, 125)
+    for build in ("flat", "kept_pairs")
+] + [("near_ties", c, "direct") for c in (1, 125)]
+
+
+@pytest.mark.parametrize("case,subsample_c,build", _SQ_CASES)
+def test_sorted_query_native_matches_numpy_twin(case, subsample_c, build):
+    """GenomeProfile.sorted_query's C build (csrc/pairstats.c::
+    galah_sorted_query, over flat_hashes) equals the numpy twin over
+    windows() element for element — hashes, window ids with equal
+    hashes in ascending window order, and per-window totals — for the
+    flat and the kept-pairs profile builds, dense and subsampled."""
+    from galah_tpu.ops import fragment_ani as fa
+    from galah_tpu.utils import timing
+
+    native = _sq_profile(case, subsample_c, build)
+    twin = _sq_profile(case, subsample_c, build)
+    before = timing.GLOBAL.counters().get("fragment-sorted-query-native", 0)
+    got = native.sorted_query()
+    assert timing.GLOBAL.counters()["fragment-sorted-query-native"] \
+        == before + 1
+    assert native._np_windows is None
+    want = fa.sorted_query_numpy(twin.windows())
+    assert got[2].shape == (native.n_windows,)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+    if case == "sentinel_windows":
+        assert (got[2][1:4] == 0).all() and got[2][0] > 0
+    if case in ("heavy_repeats", "near_ties") and subsample_c == 1:
+        # ties are exercised: some hash recurs in several windows
+        qh, qw, _ = got
+        tie = qh[1:] == qh[:-1]
+        assert (tie & (qw[1:] != qw[:-1])).any()

@@ -291,6 +291,62 @@ def test_bidirectional_values_parity_under_env_pins(pairs, monkeypatch):
     assert any(v is not None for v in outs["pallas"])
 
 
+def _fresh_profiles(subsample_c):
+    """New profiles on every call, so no sorted query is cached yet:
+    a base genome, a 2% mutant, a repeat-tiled and an ambiguous one."""
+    rng = np.random.default_rng(31)
+    base = rng.integers(0, 4, size=6_000).astype(np.uint8)
+    mut = base.copy()
+    sites = rng.random(base.shape[0]) < 0.02
+    mut[sites] = (mut[sites] + 1) % 4
+    amb = base.copy()
+    amb[1_000:2_200] = 255
+    tiled = np.tile(rng.integers(0, 4, size=400).astype(np.uint8), 12)
+    return [fa.build_profile(_genome(codes, name), K, FRAGLEN,
+                             subsample_c=subsample_c)
+            for name, codes in (("base", base), ("mut", mut),
+                                ("amb", amb), ("tiled", tiled))]
+
+
+@pytest.mark.parametrize("subsample_c", [1, SUB_C])
+def test_native_sorted_query_keeps_directed_ani_bit_identical(
+        subsample_c, monkeypatch):
+    """directed_ani_batch gives the same DirectedANI values from the C
+    sorted-query build, under the C merge and the Pallas strategy, as
+    from the numpy twin (GALAH_TPU_NO_CPAIRSTATS=1, Pallas);
+    `fragment-sorted-query-native` counts each distinct query genome
+    built in C, and none under the fallback."""
+    import sys
+
+    import galah_tpu.ops as ops_pkg
+
+    results, native = {}, {}
+    for name, strategy, no_c in (("c", "c", False),
+                                 ("pallas", "pallas", False),
+                                 ("fallback", "pallas", True)):
+        profiles = _fresh_profiles(subsample_c)
+        pairs = [(q, r) for q in profiles for r in profiles if q is not r]
+        with monkeypatch.context() as mp:
+            mp.setenv("GALAH_TPU_FRAGMENT_STRATEGY", strategy)
+            if no_c:
+                # a fresh import of the binding honours the switch
+                mp.setenv("GALAH_TPU_NO_CPAIRSTATS", "1")
+                mp.delitem(sys.modules, "galah_tpu.ops._cpairstats",
+                           raising=False)
+                mp.delattr(ops_pkg, "_cpairstats", raising=False)
+            before = timing.GLOBAL.counters()
+            results[name] = fa.directed_ani_batch(pairs, FLOOR, FRAC,
+                                                  threads=2)
+            delta = _counter_delta(before, timing.GLOBAL.counters())
+        native[name] = delta.get("fragment-sorted-query-native", 0)
+        # the C build never assembles the windows() matrix
+        assert all((p._np_windows is None) != no_c for p in profiles)
+    assert native == {"c": 4, "pallas": 4, "fallback": 0}
+    assert any(d.ani > 0.9 and d.frags_matching > 0
+               for d in results["c"])
+    assert results["c"] == results["pallas"] == results["fallback"]
+
+
 # -- strategy resolution ---------------------------------------------
 
 
