@@ -5,7 +5,10 @@ and a traffic mix; both are files found by name:
 
   benchmark/configs/<config>.json   the `galah-tpu cluster` arguments of
                                     the deployment, and the limits of
-                                    the check
+                                    the check; refused as the cell is
+                                    resolved where the job and the
+                                    reference would not both run it
+                                    (check_config)
   benchmark/traffic/<traffic>.json  the catalogue one job clusters
                                     (benchmark/corpus.py)
   benchmark/metrics/<metric>.py     one reader per per-layer metric,
@@ -80,6 +83,48 @@ def load_manifest(root: Path = ROOT) -> dict:
     return json.loads((root / "BENCHMARK.json").read_text())
 
 
+# the widths that the program and the reference both hold fixed
+FIXED_WIDTHS = {"skani_kmer": reference.SKANI_K,
+                "marker_compression": reference.MARKER_C,
+                "screen_identity": reference.SCREEN_IDENTITY,
+                "minhash_kmer": reference.FINCH_K,
+                "minhash_sketch_size": reference.SKETCH_SIZE}
+
+
+def check_config(config: dict) -> None:
+    """Refuse a configuration that the job and the reference would not
+    both run as it says, naming the key: a method the reference lacks,
+    a width that neither side can change, a fragment length that
+    disagrees with the job's, or an ANI subsample out of the program's
+    range."""
+    methods = config["methods"]
+    widths = config.get("widths", {})
+    if methods["precluster"] not in reference.PRECLUSTER_METHODS:
+        raise ValueError(f"methods.precluster {methods['precluster']!r}: "
+                         "the reference runs "
+                         f"{sorted(reference.PRECLUSTER_METHODS)}")
+    if methods["cluster"] not in reference.CLUSTER_METHODS:
+        raise ValueError(f"methods.cluster {methods['cluster']!r}: the "
+                         f"reference runs {sorted(reference.CLUSTER_METHODS)}")
+    for key, value in widths.items():
+        if key == "fragment_length":
+            if value != methods["fragment_length"]:
+                raise ValueError(
+                    f"widths.fragment_length {value!r} differs from "
+                    f"methods.fragment_length {methods['fragment_length']!r}")
+        elif key == "ani_subsample":
+            if (isinstance(value, bool) or not isinstance(value, int)
+                    or not 1 <= value <= reference.MARKER_C):
+                raise ValueError(f"widths.ani_subsample {value!r}: a whole "
+                                 f"number in [1, {reference.MARKER_C}]")
+        elif key not in FIXED_WIDTHS:
+            raise ValueError(f"widths.{key}: neither the job nor the "
+                             "reference takes it")
+        elif value != FIXED_WIDTHS[key]:
+            raise ValueError(f"widths.{key} {value!r}: the program and the "
+                             f"reference hold {FIXED_WIDTHS[key]!r}")
+
+
 def resolve_cell(manifest: dict, name: str,
                  bench_dir: Path = BENCH_DIR) -> Cell:
     """The cell `name` with its configuration, traffic and the metrics
@@ -90,6 +135,8 @@ def resolve_cell(manifest: dict, name: str,
                        f"have {sorted(cells)}")
     w = cells[name]
     config_file = bench_dir / "configs" / f"{w['config']}.json"
+    config = json.loads(config_file.read_text())
+    check_config(config)
 
     # One rule for both kinds: a metric that lists `workloads` goes to
     # those cells; one that does not goes to every cell (end to end) or
@@ -100,8 +147,7 @@ def resolve_cell(manifest: dict, name: str,
     layer = [m for m in manifest["per_layer"]
              if name in m.get("workloads", [name] if m["moves"] in reported
                               else [])]
-    return Cell(name=name, chips=int(w["chips"]),
-                config=json.loads(config_file.read_text()),
+    return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=corpus.load_traffic(
                     bench_dir / "traffic" / f"{w['traffic']}.json"),
                 end_to_end=e2e, per_layer=layer)
@@ -153,6 +199,7 @@ def cluster_argv(config: dict, files: dict, out: Path, threads: int,
             "--precluster-ani", str(m["precluster_ani"]),
             "--min-aligned-fraction", str(m["min_aligned_fraction"]),
             "--fragment-length", str(m["fragment_length"]),
+            "--ani-subsample", str(reference.ani_subsample(config)),
             "--threads", str(threads),
             "--output-cluster-definition", str(out / "clusters.tsv"),
             "--run-report", str(out / "report.json")]

@@ -18,14 +18,22 @@ implements, written from that description and the MurmurHash3 spec:
              2^64/1000; pairs i<j with marker containment |A&B| /
              min(|A|,|B|) >= 0.8^15 are screened; each screened pair
              gets exact fragment ANI, kept where >= the threshold.
+  subsample  FracMinHash compression s of fragment ANI (the job's
+             `--ani-subsample`, 1 by default): a canonical 15-mer takes
+             part only where its hash is below 2^64 // s, both in the
+             reference's distinct set and among the query positions.
+             The markers, below 2^64/1000, are a subset of any sample
+             with s <= 1000, so the screen does not change.
   fragment   the query is cut into fragments of `fraglen` positions; a
-  ANI        position holds a 15-mer that lies inside its fragment and
-             its contig. Per fragment: total positions and matched ones
-             (k-mer in the reference's distinct set). A fragment counts
-             if total >= half of fraglen-k+1; it aligns if matched/total
-             >= 0.8^k. The collision rate r is the mean matched fraction
-             of counted, unaligned fragments; identity = clip((c - r) /
-             (1 - r))^(1/k); directed ANI = mean identity over aligned
+  ANI        position holds a sampled 15-mer that lies inside its
+             fragment and its contig. Per fragment: total positions and
+             matched ones (k-mer in the reference's distinct set). A
+             fragment counts if total >= half of fraglen-k+1 divided
+             by the compression (and >= 1); it aligns if matched/total
+             >= 0.8^k. The collision rate r is the mean matched
+             fraction of counted, unaligned fragments; identity =
+             clip((c - r) / (1 - r))^(1/k) for a fragment's matched
+             fraction c; directed ANI = mean identity over aligned
              fragments, aligned fraction = aligned / counted. A pair
              passes if either direction's aligned fraction reaches
              min_aligned_fraction (and something aligned); its ANI is
@@ -60,6 +68,8 @@ MARKER_C = 1000
 SCREEN_IDENTITY = 0.80
 IDENTITY_FLOOR = 0.80
 MIN_WINDOW_VALID_FRAC = 0.5
+PRECLUSTER_METHODS = ("skani", "finch")
+CLUSTER_METHODS = ("skani",)
 
 _ASCII = np.frombuffer(b"ACGT", dtype=np.uint8)
 _C1 = np.uint64(0x87C37B91114253D5)
@@ -196,12 +206,20 @@ class KmerView:
         return murmur3_h1(ascii_words(ids, self.k), self.k)
 
 
+def ani_subsample(config: dict) -> int:
+    """The FracMinHash compression c of a configuration's exact
+    fragment ANI: what the job gets as `--ani-subsample` and the
+    reference applies; 1, every k-mer, where it is not given."""
+    return config.get("widths", {}).get("ani_subsample", 1)
+
+
 class GenomeData:
-    """What the reference needs of one genome."""
+    """What the reference needs of one genome; its fragment-ANI k-mers
+    sampled at compression `subsample`."""
 
     def __init__(self, name: str, contigs: Sequence[np.ndarray],
                  need_finch: bool, need_skani: bool,
-                 fraglen: int) -> None:
+                 fraglen: int, subsample: int = 1) -> None:
         self.name = name
         if need_finch:
             view = KmerView(contigs, FINCH_K)
@@ -215,13 +233,21 @@ class GenomeData:
         if need_skani:
             view = KmerView(contigs, SKANI_K)
             distinct = np.unique(view.canon[view.valid])
-            self.distinct = distinct.astype(np.uint32)
             h = view.hashes(distinct)
             self.markers = np.sort(h[h < np.uint64((1 << 64) // MARKER_C)])
+            sampled = view.valid
+            if subsample > 1:
+                keep = h < np.uint64((1 << 64) // subsample)
+                sampled = view.valid.copy()
+                sampled[sampled] = keep[np.searchsorted(
+                    distinct, view.canon[sampled])]
+                distinct = distinct[keep]
+            self.distinct = distinct.astype(np.uint32)
             self.n_windows = -(-view.n_pos // fraglen)
-            # query positions: k-mer inside its fragment and its contig
+            # query positions: sampled k-mer inside its fragment and its
+            # contig
             pos = np.flatnonzero(
-                view.valid
+                sampled
                 & (np.arange(view.n_pos) % fraglen < fraglen - SKANI_K + 1))
             self.qids = view.canon[pos].astype(np.uint32)
             win = pos // fraglen
@@ -233,10 +259,12 @@ class GenomeData:
 
 def build_genomes(genomes: Sequence[Tuple[str, Sequence[np.ndarray]]],
                   need_finch: bool, need_skani: bool, fraglen: int,
-                  threads: int) -> Dict[str, GenomeData]:
+                  threads: int, subsample: int = 1
+                  ) -> Dict[str, GenomeData]:
     def one(item):
         name, contigs = item
-        return GenomeData(name, contigs, need_finch, need_skani, fraglen)
+        return GenomeData(name, contigs, need_finch, need_skani, fraglen,
+                          subsample)
 
     with ThreadPoolExecutor(max_workers=max(1, threads)) as pool:
         return {g.name: g for g in pool.map(one, genomes)}
@@ -322,13 +350,14 @@ class Directed:
         self.totals = totals
 
 
-def directed_ani(d: Directed, fraglen: int, dtype=np.float64
-                 ) -> Tuple[float, int, int]:
-    """(ani, aligned fragments, counted fragments)."""
+def directed_ani(d: Directed, fraglen: int, dtype=np.float64,
+                 subsample: int = 1) -> Tuple[float, int, int]:
+    """(ani, aligned fragments, counted fragments) of counts taken at
+    compression `subsample`."""
     k = SKANI_K
     matched = d.matched.astype(dtype)
     total = d.totals.astype(dtype)
-    min_valid = MIN_WINDOW_VALID_FRAC * (fraglen - k + 1)
+    min_valid = MIN_WINDOW_VALID_FRAC * (fraglen - k + 1) / subsample
     ok = total >= max(min_valid, 1.0)
     c = np.where(ok, matched / np.maximum(total, dtype(1.0)), dtype(0.0))
     aligned = ok & (c >= dtype(IDENTITY_FLOOR ** k))
@@ -421,10 +450,11 @@ class FragmentANI:
     scratch table, eight at a time."""
 
     def __init__(self, data: Dict[str, GenomeData], min_af: float,
-                 fraglen: int, threads: int) -> None:
+                 fraglen: int, threads: int, subsample: int = 1) -> None:
         self.data = data
         self.min_af = min_af
         self.fraglen = fraglen
+        self.subsample = subsample
         self.counts: Dict[Tuple[str, str], Directed] = {}
         self.pool = ThreadPoolExecutor(max_workers=max(1, threads))
         self.scratch = MembershipTable(self.pool)
@@ -480,7 +510,8 @@ class FragmentANI:
             self.scratch.clear()
 
     def directed(self, q: str, r: str, dtype=np.float64):
-        return directed_ani(self.counts[(q, r)], self.fraglen, dtype)
+        return directed_ani(self.counts[(q, r)], self.fraglen, dtype,
+                            self.subsample)
 
     def ani(self, a: str, b: str, dtype=np.float64) -> Optional[float]:
         return combine(self.directed(a, b, dtype),
@@ -652,8 +683,8 @@ class JobResult:
         self.order = order          # names in quality order
         self.pre = pre              # precluster pairs -> ANI
         self.clusters = clusters    # names, representative first
-        # name -> (query positions, distinct 15-mers): the sizes of the
-        # fragment-membership test
+        # name -> (query positions, distinct 15-mers), as sampled: the
+        # sizes of the fragment-membership test
         self.sizes = sizes
         # the clusterer's ANI of pairs asked for after solving
         self.clusterer: Dict[frozenset, Optional[float]] = {}
@@ -674,9 +705,9 @@ class Job:
         methods = config["methods"]
         self.pre_method = methods["precluster"]
         self.cl_method = methods["cluster"]
-        if self.cl_method != "skani":
+        if self.cl_method not in CLUSTER_METHODS:
             raise ValueError(f"no reference for cluster {self.cl_method!r}")
-        if self.pre_method not in ("skani", "finch"):
+        if self.pre_method not in PRECLUSTER_METHODS:
             raise ValueError(
                 f"no reference for precluster {self.pre_method!r}")
         self.ani_thr = methods["ani"] / 100.0
@@ -684,6 +715,7 @@ class Job:
         if self.pre_method == "skani" and self.cl_method == "skani":
             self.pre_thr = self.ani_thr
         fraglen = int(methods["fragment_length"])
+        subsample = ani_subsample(config)
         quality = config["quality"]
         self.order = quality_order(
             records, quality["min_completeness"] / 100.0,
@@ -691,9 +723,9 @@ class Job:
         self.data = build_genomes(
             [(n, contigs[n]) for n in self.order],
             need_finch=self.pre_method == "finch", need_skani=True,
-            fraglen=fraglen, threads=threads)
+            fraglen=fraglen, threads=threads, subsample=subsample)
         self.frag = FragmentANI(self.data, methods["min_aligned_fraction"]
-                                / 100.0, fraglen, threads)
+                                / 100.0, fraglen, threads, subsample)
         self.screened = (screen_pairs(self.order, self.data)
                          if self.pre_method == "skani" else None)
 

@@ -6,6 +6,8 @@ import re
 import shutil
 from pathlib import Path
 
+import pytest
+
 import harness
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -109,6 +111,68 @@ def test_new_cell_needs_only_files_and_a_manifest_entry(tmp_path):
         jobs = [1, 2, 3]
 
     assert read(Ctx()) == 3.0
+
+
+def _files():
+    return {"genome_list": "list.txt", "quality": "quality.tsv"}
+
+
+def _flag(argv, flag):
+    assert argv.count(flag) == 1
+    return argv[argv.index(flag) + 1]
+
+
+def test_cluster_argv_carries_the_ani_subsample(tmp_path):
+    config = json.loads(
+        (BENCH / "configs" / "species95_skani.json").read_text())
+    config["widths"]["ani_subsample"] = 125
+    argv = harness.cluster_argv(config, _files(), tmp_path, 4, None)
+    assert _flag(argv, "--ani-subsample") == "125"
+    del config["widths"]["ani_subsample"]
+    argv = harness.cluster_argv(config, _files(), tmp_path, 4, None)
+    assert _flag(argv, "--ani-subsample") == "1"
+
+
+def test_committed_configs_run_every_kmer(tmp_path):
+    """Both committed configurations hold c=1, so their jobs get the
+    program's own default and nothing they run changes."""
+    for c in _manifest()["configs"]:
+        config = json.loads((ROOT / c["file"]).read_text())
+        argv = harness.cluster_argv(config, _files(), tmp_path, 4, None)
+        assert _flag(argv, "--ani-subsample") == "1"
+
+
+MISLABELS = [
+    ("methods", "precluster", "dashing", "methods.precluster"),
+    ("methods", "cluster", "fastani", "methods.cluster"),
+    ("widths", "fragment_length", 2000, "widths.fragment_length"),
+    ("widths", "skani_kmer", 16, "widths.skani_kmer"),
+    ("widths", "marker_compression", 500, "widths.marker_compression"),
+    ("widths", "screen_identity", 0.9, "widths.screen_identity"),
+    ("widths", "minhash_kmer", 15, "widths.minhash_kmer"),
+    ("widths", "minhash_sketch_size", 2000, "widths.minhash_sketch_size"),
+    ("widths", "ani_subsample", 0, "widths.ani_subsample"),
+    ("widths", "ani_subsample", 1001, "widths.ani_subsample"),
+    ("widths", "ani_subsample", 12.5, "widths.ani_subsample"),
+    ("widths", "hll_precision", 12, "widths.hll_precision"),
+]
+
+
+@pytest.mark.parametrize("group,key,value,named", MISLABELS,
+                         ids=[f"{m[1]}={m[2]}" for m in MISLABELS])
+def test_resolve_cell_refuses_a_config_it_would_not_run(
+        tmp_path, group, key, value, named):
+    bench = tmp_path / "benchmark"
+    for sub in ("configs", "traffic"):
+        shutil.copytree(BENCH / sub, bench / sub)
+    config = json.loads(
+        (BENCH / "configs" / "strain99_finch.json").read_text())
+    config[group][key] = value
+    (bench / "configs" / "strain99_finch.json").write_text(
+        json.dumps(config))
+    with pytest.raises(ValueError, match=re.escape(named)):
+        harness.resolve_cell(_manifest(), "strain99.bigfamily",
+                             bench_dir=bench)
 
 
 def _report(**kw):
