@@ -135,23 +135,29 @@ class ProfileStore:
 
     def _build(self, genome) -> GenomeProfile:
         """One genome's profile: the span `profile.build`; its bases
-        count in `profile-bp`."""
+        count in `profile-bp`, the k-mer positions its FracMinHash
+        sample keeps for exact ANI in `profile-kept-kmers`."""
         timing.counter("profile-bp", genome.length)
         with timing.stage("profile.build"):
-            return fragment_ani.build_profile(
+            prof = fragment_ani.build_profile(
                 genome, k=self.k, fraglen=self.fraglen,
                 subsample_c=self.subsample_c,
                 hash_algorithm=self.hash_algorithm)
+        timing.counter("profile-kept-kmers", prof.query_positions())
+        return prof
 
     def _build_batch(self, genomes) -> "list[GenomeProfile]":
         """A group's profiles in grouped hash dispatches: one
         `profile.build` span for the group."""
         timing.counter("profile-bp", sum(g.length for g in genomes))
         with timing.stage("profile.build"):
-            return fragment_ani.build_profiles_batch(
+            profs = fragment_ani.build_profiles_batch(
                 genomes, k=self.k, fraglen=self.fraglen,
                 subsample_c=self.subsample_c,
                 hash_algorithm=self.hash_algorithm)
+        timing.counter("profile-kept-kmers",
+                       sum(p.query_positions() for p in profs))
+        return profs
 
     def get(self, path: str) -> GenomeProfile:
         prof = self._cache.get(path)

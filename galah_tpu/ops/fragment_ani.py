@@ -135,6 +135,23 @@ class GenomeProfile:
                 timing.counter("fragment-sorted-query-native", 1)
         return self._np_sorted_query
 
+    def query_positions(self) -> int:
+        """The profile's query length: surviving k-mer positions that
+        lie inside their fragment, which is the sum of sorted_query()'s
+        totals and the count of windows()' non-SENTINEL entries,
+        counted from the profile walk's kept positions where it left
+        them, else from flat_hashes, without building either."""
+        flat, length = self.flat_hashes, self.fraglen
+        inner = max(length - (self.k - 1), 0)
+        if self._kept_pos is not None:
+            return int(np.count_nonzero(self._kept_pos % length < inner))
+        full = flat.shape[0] // length
+        sentinel = np.uint64(SENTINEL)
+        rows = flat[:full * length].reshape(full, length)[:, :inner]
+        return (int(np.count_nonzero(rows != sentinel))
+                + int(np.count_nonzero(
+                    flat[full * length:][:inner] != sentinel)))
+
     def padded_windows(self) -> np.ndarray:
         if self._np_windows_padded is None:
             self._np_windows_padded = pad_windows(self.windows())
@@ -1086,60 +1103,65 @@ def _directed_ani_batch_pallas(
         groups.setdefault((q.k, q.fraglen, q.subsample_c), []).append(n)
 
     for (k, fraglen, subsample_c), idxs in groups.items():
-        # pairs sharing a reference run back to back, so its planes
-        # stay resident while its queries stream past; the query order
-        # reverses from one reference to the next, so the queries the
-        # cache still holds come first
-        by_ref: "dict[int, list[int]]" = {}
-        for n in idxs:
-            by_ref.setdefault(id(queries[n][1]), []).append(n)
-        idxs = []
-        for g, group in enumerate(by_ref.values()):
-            group.sort(key=lambda n: id(queries[n][0]), reverse=g % 2 == 1)
-            idxs += group
-        items = []
-        for n in idxs:
-            q, r = queries[n]
-            items.append((q.sorted_query()[0], r.ref_set))
+        with timing.stage("fragment-ani.plan"):
+            # pairs sharing a reference run back to back, so its planes
+            # stay resident while its queries stream past; the query
+            # order reverses from one reference to the next, so the
+            # queries the cache still holds come first
+            by_ref: "dict[int, list[int]]" = {}
+            for n in idxs:
+                by_ref.setdefault(id(queries[n][1]), []).append(n)
+            idxs = []
+            for g, group in enumerate(by_ref.values()):
+                group.sort(key=lambda n: id(queries[n][0]),
+                           reverse=g % 2 == 1)
+                idxs += group
+            items = [(queries[n][0].sorted_query()[0],
+                      queries[n][1].ref_set) for n in idxs]
         matched_by_item = {}
-        for j, flags in pallas_fragment.iter_element_hits(
-                items, interpret=interpret):
-            _qh, qw, totals = queries[idxs[j]][0].sorted_query()
-            if not flags.shape[0]:
-                # an empty query matches in no window
-                matched_by_item[j] = np.zeros(totals.shape[0], np.int32)
-                continue
-            with timing.stage("fragment-ani.fold", {"direction": j}):
-                matched_by_item[j] = _fold_flags(flags, qw,
-                                                 totals.shape[0])
+        # the pipeline's self time is its own bookkeeping between the
+        # launch, fetch and fold spans
+        with timing.stage("fragment-ani.pipeline"):
+            for j, flags in pallas_fragment.iter_element_hits(
+                    items, interpret=interpret):
+                _qh, qw, totals = queries[idxs[j]][0].sorted_query()
+                if not flags.shape[0]:
+                    # an empty query matches in no window
+                    matched_by_item[j] = np.zeros(totals.shape[0],
+                                                  np.int32)
+                    continue
+                with timing.stage("fragment-ani.fold", {"direction": j}):
+                    matched_by_item[j] = _fold_flags(flags, qw,
+                                                     totals.shape[0])
 
-        matched_parts, total_parts, starts, live = [], [], [], []
-        seg = 0
-        for j, n in enumerate(idxs):
-            q, _r = queries[n]
-            _qh, qw, totals = q.sorted_query()
-            w = totals.shape[0]
-            if w == 0:
-                # reduceat cannot represent empty segments; the
-                # zero-window result is all-zero by definition
-                out[n] = DirectedANI(0.0, 0.0, 0, 0)
+        with timing.stage("fragment-ani.collect"):
+            matched_parts, total_parts, starts, live = [], [], [], []
+            seg = 0
+            for j, n in enumerate(idxs):
+                q, _r = queries[n]
+                _qh, qw, totals = q.sorted_query()
+                w = totals.shape[0]
+                if w == 0:
+                    # reduceat cannot represent empty segments; the
+                    # zero-window result is all-zero by definition
+                    out[n] = DirectedANI(0.0, 0.0, 0, 0)
+                    continue
+                matched_parts.append(matched_by_item[j])
+                total_parts.append(totals)
+                starts.append(seg)
+                seg += w
+                live.append(n)
+            if not live:
                 continue
-            matched_parts.append(matched_by_item[j])
-            total_parts.append(totals)
-            starts.append(seg)
-            seg += w
-            live.append(n)
-        if not live:
-            continue
-        with timing.stage("fragment-ani.reduce"):
-            ani, af, fm, ft = _directed_from_counts_arrays(
-                np.concatenate(matched_parts),
-                np.concatenate(total_parts),
-                np.asarray(starts, dtype=np.int64), k, fraglen,
-                subsample_c, identity_floor, min_window_valid_frac)
-        for i, n in enumerate(live):
-            out[n] = DirectedANI(float(ani[i]), float(af[i]),
-                                 int(fm[i]), int(ft[i]))
+            with timing.stage("fragment-ani.reduce"):
+                ani, af, fm, ft = _directed_from_counts_arrays(
+                    np.concatenate(matched_parts),
+                    np.concatenate(total_parts),
+                    np.asarray(starts, dtype=np.int64), k, fraglen,
+                    subsample_c, identity_floor, min_window_valid_frac)
+            for i, n in enumerate(live):
+                out[n] = DirectedANI(float(ani[i]), float(af[i]),
+                                     int(fm[i]), int(ft[i]))
     return out  # type: ignore[return-value]
 
 
@@ -1211,12 +1233,10 @@ def bidirectional_ani_batch(
         [(a, b) for a, b in pairs] + [(b, a) for a, b in pairs],
         identity_floor=identity_floor, threads=threads)
     n = len(pairs)
-    out = []
-    for i in range(n):
-        ab, ba = directed[i], directed[n + i]
-        out.append((_combine_bidirectional(ab, ba, min_aligned_frac),
-                    ab, ba))
-    return out
+    with timing.stage("fragment-ani.combine"):
+        return [(_combine_bidirectional(directed[i], directed[n + i],
+                                        min_aligned_frac),
+                 directed[i], directed[n + i]) for i in range(n)]
 
 
 def bidirectional_ani_values(

@@ -4,10 +4,13 @@ exact ANI and profiles.
 Every StageTimer stage and flow span also holds a jax.profiler
 TraceAnnotation, so a profiler session shows it on the xplane's host
 plane beside the device ops. Exact ANI names its host work per directed
-pair (`fragment-ani.launch/fetch/fold`, `.sort-queries`, `.reduce`) and
-counts its directions (`fragment-ani-directions`, every strategy);
+pair (`fragment-ani.launch/fetch/fold`, under `.pipeline`) and per
+batch or group (`.sort-queries`, `.plan`, `.collect` with `.reduce`
+inside it, `.combine`), and counts its directions
+(`fragment-ani-directions`, every strategy);
 profiles name each genome's read and build (`profile.read`,
-`profile.build`) and count the bases profiled (`profile-bp`).
+`profile.build`) and count the bases profiled (`profile-bp`) and the
+k-mer positions their sample keeps (`profile-kept-kmers`).
 """
 
 import glob
@@ -139,13 +142,18 @@ def test_directions_counted_and_spanned_per_strategy(
     monkeypatch.setenv("GALAH_TPU_FRAGMENT_STRATEGY", strategy)
     pf.clear_plane_cache()
     timing.reset()
-    values = fa.bidirectional_ani_values(direction_pairs,
-                                         min_aligned_frac=0.15)
+    with timing.stage("fragment-ani"):
+        values = fa.bidirectional_ani_values(direction_pairs,
+                                             min_aligned_frac=0.15)
     assert len(values) == len(direction_pairs)
     directions = 2 * len(direction_pairs)
     assert timing.GLOBAL.counters()["fragment-ani-directions"] == \
         directions
     spans = _span_counts()
+    # the per-pair combine of both directions, once per batch
+    assert spans["fragment-ani.combine"] == 1
+    (node,) = _find(timing.GLOBAL.tree(), "fragment-ani")
+    children = {c["name"]: c for c in node["children"]}
     if strategy == "pallas":
         live = sum(1 for a, b in direction_pairs for q in (a, b)
                    if q.sorted_query()[0].shape[0])
@@ -153,10 +161,25 @@ def test_directions_counted_and_spanned_per_strategy(
         for name in ("fragment-ani.launch", "fragment-ani.fetch",
                      "fragment-ani.fold"):
             assert spans[name] == live, name
-        assert spans["fragment-ani.reduce"] == 1
-        assert spans["fragment-ani.sort-queries"] == 1
+        for name in ("fragment-ani.reduce", "fragment-ani.sort-queries",
+                     "fragment-ani.plan", "fragment-ani.pipeline",
+                     "fragment-ani.collect"):
+            assert spans[name] == 1, name
+        # every stretch of the batch's host work is under a named child
+        assert set(children) == {
+            "fragment-ani.sort-queries", "fragment-ani.plan",
+            "fragment-ani.pipeline", "fragment-ani.collect",
+            "fragment-ani.combine"}
+        assert {c["name"] for c in
+                children["fragment-ani.pipeline"]["children"]} == {
+            "fragment-ani.launch", "fragment-ani.fetch",
+            "fragment-ani.fold"}
+        assert [c["name"] for c in
+                children["fragment-ani.collect"]["children"]] == [
+            "fragment-ani.reduce"]
     else:
         assert "fragment-ani.launch" not in spans
+        assert "fragment-ani.pipeline" not in spans
 
 
 def test_directions_counted_on_the_batched_c_path(monkeypatch):
@@ -224,7 +247,12 @@ def test_profile_spans_per_genome(tmp_path, threads):
     spans = _span_counts()
     assert spans["profile.read"] == len(paths)
     assert spans["profile.build"] == len(paths)
-    assert timing.GLOBAL.counters()["profile-bp"] == 5 * 6_000
+    counters = timing.GLOBAL.counters()
+    assert counters["profile-bp"] == 5 * 6_000
+    # at c=1 the sample keeps every valid position inside its fragment
+    kept = sum(store.get(p).query_positions() for p in paths)
+    assert counters["profile-kept-kmers"] == kept
+    assert 0 < kept < 5 * 6_000
     # the workers adopt the submitting stage: both nest under it
     (node,) = _find(timing.GLOBAL.tree(), "profile-genomes")
     assert {c["name"] for c in node["children"]} == {"profile.read",
